@@ -1,42 +1,65 @@
 """Shared runner for the benches' --devices axis.
 
-``--xla_force_host_platform_device_count`` must precede the first jax
-import, and forcing it in the parent process would also split the CPU
-across the virtual devices for the single-device sections — silently
-skewing the PR-over-PR trajectory of the main numbers.  So the sharded
-section re-runs the calling script in a SUBPROCESS (its ``--sharded-only``
-mode) with the flag in the environment and reads one ``RESULT <json>``
-line back.
+A device belongs to one process at a time: once a process has initialized
+a JAX backend it holds the chip, and a child that asks for it then fails or
+hangs.  So the axis runs in this process whenever its devices are (or, by
+the forced virtual device count in ``XLA_FLAGS``, will be) visible here,
+and a child is started only while this process has initialized no backend
+— the benches call this first, before their own measurements.  The child
+re-runs the calling script in its ``--sharded-only`` mode under a forced
+``devices``-wide virtual host platform (which also keeps the CPU threads
+of the single-device sections whole) and prints one ``RESULT <json>``
+line.  Any failure raises: a missing axis is never a silent skip.
 """
 from __future__ import annotations
 
 import json
 import os
+import re
 import subprocess
 import sys
 
+_FORCE = "xla_force_host_platform_device_count"
 
-def run_sharded_subprocess(script_file: str, script_args: list[str],
-                           devices: int):
-    """Re-invoke ``script_file --sharded-only *script_args`` under a forced
-    ``devices``-wide virtual host platform; returns the parsed RESULT
-    payload, or None on failure / nothing measured.  A device count already
-    forced in the parent's XLA_FLAGS is respected, not duplicated."""
+
+def _backend_initialized() -> bool:
+    from jax._src import xla_bridge
+
+    return xla_bridge.backends_are_initialized()
+
+
+def _forced_devices() -> int:
+    m = re.search(_FORCE + r"=(\d+)", os.environ.get("XLA_FLAGS", ""))
+    return int(m.group(1)) if m else 0
+
+
+def run_devices_axis(script_file: str, script_args: list[str], devices: int,
+                     run_here):
+    """The --devices axis payload: ``run_here()`` in this process when
+    ``devices`` devices are visible here, else ``script_file --sharded-only
+    *script_args`` in a child.  Raises when the axis cannot run."""
+    if _backend_initialized() or _forced_devices() >= devices:
+        import jax
+
+        have = len(jax.devices())
+        if have < devices:
+            raise RuntimeError(
+                f"the --devices {devices} axis needs {devices} devices; this "
+                f"process holds a {jax.default_backend()} backend with "
+                f"{have}, and a child started now could not take them")
+        return run_here()
     env = dict(os.environ)
-    if "xla_force_host_platform_device_count" not in env.get("XLA_FLAGS", ""):
-        env["XLA_FLAGS"] = (
-            env.get("XLA_FLAGS", "")
-            + f" --xla_force_host_platform_device_count={devices}").strip()
+    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
+                        + f" --{_FORCE}={devices}").strip()
     r = subprocess.run([sys.executable, os.path.abspath(script_file),
                         "--sharded-only"] + script_args,
                        capture_output=True, text=True, env=env)
-    if r.returncode != 0:
-        print(f"sharded axis failed:\n{r.stderr[-2000:]}")
-        return None
     print("\n".join(l for l in r.stdout.splitlines()
                     if not l.startswith("RESULT ")))
+    if r.returncode != 0:
+        raise RuntimeError(f"--devices {devices} axis failed "
+                           f"(exit {r.returncode}):\n{r.stderr[-2000:]}")
     lines = [l for l in r.stdout.splitlines() if l.startswith("RESULT ")]
     if not lines:
-        print("sharded axis produced no RESULT line")
-        return None
-    return json.loads(lines[0][len("RESULT "):]) or None
+        raise RuntimeError(f"--devices {devices} axis printed no RESULT line")
+    return json.loads(lines[0][len("RESULT "):])

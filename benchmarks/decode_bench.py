@@ -202,9 +202,8 @@ def bench_sharded(archs, batch: int, prompt_len: int, n_new: int, reps: int,
     from repro.serving import ServingEngine, make_decode_mesh, pim_bytes
 
     if len(jax.devices()) < devices:
-        print(f"only {len(jax.devices())} devices visible; skipping the "
-              f"--devices {devices} axis (set XLA_FLAGS before any jax import)")
-        return []
+        raise RuntimeError(f"only {len(jax.devices())} devices visible; the "
+                           f"--devices {devices} axis needs {devices}")
     mesh = make_decode_mesh(devices)
     rows = []
     for arch in archs:
@@ -261,10 +260,30 @@ def main(argv=None) -> None:
         archs, batch, prompt, new, reps = (ARCHS, args.batch, args.prompt,
                                            args.new_tokens, args.reps)
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
+    def sharded_axis():
+        return bench_sharded(archs, batch, prompt, new, reps, args.devices)
+
     if args.sharded_only:
-        rows = bench_sharded(archs, batch, prompt, new, reps, args.devices)
-        print("RESULT " + json.dumps(rows))
+        print("RESULT " + json.dumps(sharded_axis()))
         return
+
+    # The --devices axis first: a child may only start while this process
+    # holds no backend (bench_subproc).
+    rows = None
+    if args.devices > 1:
+        from bench_subproc import run_devices_axis
+
+        sub_args = ["--devices", str(args.devices), "--batch", str(args.batch),
+                    "--prompt", str(args.prompt),
+                    "--new-tokens", str(args.new_tokens),
+                    "--reps", str(args.reps)] + (
+                        ["--quick"] if args.quick else [])
+        rows = run_devices_axis(__file__, sub_args, args.devices,
+                                sharded_axis)
 
     import jax
 
@@ -285,17 +304,8 @@ def main(argv=None) -> None:
             "grid": bench_speculative(archs, batch, prompt, new, reps,
                                       args.speculate, args.temperature),
         }
-    if args.devices > 1:
-        from bench_subproc import run_sharded_subprocess
-
-        sub_args = ["--devices", str(args.devices), "--batch", str(args.batch),
-                    "--prompt", str(args.prompt),
-                    "--new-tokens", str(args.new_tokens),
-                    "--reps", str(args.reps)] + (
-                        ["--quick"] if args.quick else [])
-        rows = run_sharded_subprocess(__file__, sub_args, args.devices)
-        if rows:
-            result["sharded"] = {"devices": args.devices, "grid": rows}
+    if rows is not None:
+        result["sharded"] = {"devices": args.devices, "grid": rows}
     out_path = Path(args.out)
     out_path.write_text(json.dumps(result, indent=2))
     print(f"wrote {out_path}")

@@ -12,6 +12,9 @@ sys.path.insert(0, str(_ROOT))  # make `benchmarks` importable as a package
 
 def main() -> None:
     from benchmarks import decode_bench, kernel_bench, paper_tables, roofline_report
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     suites = (paper_tables.ALL + kernel_bench.ALL + roofline_report.ALL
               + decode_bench.ALL)

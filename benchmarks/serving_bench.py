@@ -496,9 +496,8 @@ def bench_sharded(arch: str, requests, slots: int, page_size: int, chunk: int,
                                pim_bytes)
 
     if len(jax.devices()) < devices:
-        print(f"only {len(jax.devices())} devices visible; skipping the "
-              f"--devices {devices} axis")
-        return {}
+        raise RuntimeError(f"only {len(jax.devices())} devices visible; the "
+                           f"--devices {devices} axis needs {devices}")
     cfg = get_reduced(arch)
     params = init_params(cfg, jax.random.PRNGKey(0))
     mesh = make_decode_mesh(devices)
@@ -755,22 +754,47 @@ def main(argv=None) -> None:
                   pool_frac=args.pool_frac, seed=args.seed,
                   scale=not args.no_scale)
 
-    if args.sharded_only:
-        from repro.configs import get_reduced
+    from repro.launch.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
+
+    def sharded_axis():
         max_seq, num_pages = pool_geometry(kw["slots"], kw["page_size"],
                                            kw["max_prompt"], kw["max_new_cap"],
                                            kw["pool_frac"])
-
         # Same trace as the main comparison, on the raw reduced config
         # (the scaled-up config exists to drown dispatch overhead, which
         # the 1-vs-N comparison does not need).
-        requests = trace_for(kw, args.arch)
-        sharded = bench_sharded(
-            args.arch, requests, kw["slots"], kw["page_size"], kw["chunk"],
-            max_seq, num_pages, args.devices)
-        print("RESULT " + json.dumps(sharded))
+        return bench_sharded(
+            args.arch, trace_for(kw, args.arch), kw["slots"],
+            kw["page_size"], kw["chunk"], max_seq, num_pages, args.devices)
+
+    if args.sharded_only:
+        print("RESULT " + json.dumps(sharded_axis()))
         return
+
+    # The --devices axis first: a child may only start while this process
+    # holds no backend (bench_subproc).
+    sharded = None
+    if args.devices > 1:
+        from bench_subproc import run_devices_axis
+
+        sub_args = ["--arch", args.arch, "--devices", str(args.devices),
+                    "--seed", str(args.seed)] + (
+                        ["--smoke"] if args.smoke else [
+                            "--requests", str(args.requests),
+                            "--slots", str(args.slots),
+                            "--page-size", str(args.page_size),
+                            "--chunk", str(args.chunk),
+                            "--mean-prompt", str(args.mean_prompt),
+                            "--mean-new", str(args.mean_new),
+                            "--mean-new-long", str(args.mean_new_long),
+                            "--long-frac", str(args.long_frac),
+                            "--max-prompt", str(args.max_prompt),
+                            "--max-new-cap", str(args.max_new_cap),
+                            "--pool-frac", str(args.pool_frac)])
+        sharded = run_devices_axis(__file__, sub_args, args.devices,
+                                   sharded_axis)
 
     import jax
 
@@ -821,26 +845,8 @@ def main(argv=None) -> None:
                  "is the per-slot proposer acceptance"),
         **row,
     })
-    if args.devices > 1:
-        from bench_subproc import run_sharded_subprocess
-
-        sub_args = ["--arch", args.arch, "--devices", str(args.devices),
-                    "--seed", str(args.seed)] + (
-                        ["--smoke"] if args.smoke else [
-                            "--requests", str(args.requests),
-                            "--slots", str(args.slots),
-                            "--page-size", str(args.page_size),
-                            "--chunk", str(args.chunk),
-                            "--mean-prompt", str(args.mean_prompt),
-                            "--mean-new", str(args.mean_new),
-                            "--mean-new-long", str(args.mean_new_long),
-                            "--long-frac", str(args.long_frac),
-                            "--max-prompt", str(args.max_prompt),
-                            "--max-new-cap", str(args.max_new_cap),
-                            "--pool-frac", str(args.pool_frac)])
-        sharded = run_sharded_subprocess(__file__, sub_args, args.devices)
-        if sharded:  # None/{} when the subprocess saw too few devices
-            result["sharded"] = sharded
+    if sharded is not None:
+        result["sharded"] = sharded
     Path(args.out).write_text(json.dumps(result, indent=2))
     fx, ct = result["fixed_batch"], result["continuous"]
     print(f"fixed batch : {fx['tokens_per_sec']:10.1f} useful tok/s "

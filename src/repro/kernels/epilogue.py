@@ -38,21 +38,39 @@ def apply_epilogue(acc, scale, bias, residual, activation: str):
     return y
 
 
-def quant_accumulate(x, w_tile, bits: int):
-    """One K-step contribution: x (bm, bk) f32 @ quantized weight tile.
+def split_activations(x, bits: int) -> tuple:
+    """The activation operands a quantized kernel streams, as XLA arrays.
 
-    bits=8: ``w_tile`` is (bk, bn) int8, dequantized at the VMEM boundary.
-    bits=4: ``w_tile`` is (bk//2, bn) nibble-packed int8 — even K rows hit
-    the low nibbles, odd K rows the high nibbles.  Shared by pim_matmul and
+    bits=8: ``(x,)``.  bits=4: ``(x[:, 0::2], x[:, 1::2])`` — the even and
+    odd K columns, which meet the low and high nibbles of the packed weight
+    rows.  The split happens here, outside the kernel, because Mosaic
+    refuses strided lane slices inside a kernel body; each half then
+    streams with a plain ``(rows, bk // 2)`` block."""
+    if bits == 8:
+        return (x,)
+    return (x[:, 0::2], x[:, 1::2])
+
+
+def quant_accumulate(xs, w_tile, bits: int):
+    """One K-step contribution: activation tiles @ quantized weight tile.
+
+    bits=8: ``xs`` is ``(x,)`` with x (bm, bk) f32 and ``w_tile`` (bk, bn)
+    int8, dequantized at the VMEM boundary.  bits=4: ``xs`` is the (even,
+    odd) K-column pair from ``split_activations``, each (bm, bk//2), and
+    ``w_tile`` is (bk//2, bn) nibble-packed int8 — even K rows in the low
+    nibbles, odd K rows in the high nibbles.  Shared by pim_matmul and
     pim_matvec so the dequant semantics can never drift between them.
     """
     if bits == 8:
+        (x,) = xs
         return jnp.dot(x, w_tile.astype(jnp.float32),
                        preferred_element_type=jnp.float32)
-    lo = (((w_tile & 0xF) ^ 8) - 8).astype(jnp.float32)
-    hi = ((((w_tile >> 4) & 0xF) ^ 8) - 8).astype(jnp.float32)
-    return (jnp.dot(x[:, 0::2], lo, preferred_element_type=jnp.float32)
-            + jnp.dot(x[:, 1::2], hi, preferred_element_type=jnp.float32))
+    x_even, x_odd = xs
+    w = w_tile.astype(jnp.int32)  # the TPU vector unit has no int8 arithmetic
+    lo = (((w & 0xF) ^ 8) - 8).astype(jnp.float32)
+    hi = ((((w >> 4) & 0xF) ^ 8) - 8).astype(jnp.float32)
+    return (jnp.dot(x_even, lo, preferred_element_type=jnp.float32)
+            + jnp.dot(x_odd, hi, preferred_element_type=jnp.float32))
 
 
 def unpack_epilogue_refs(rest, has_bias: bool, has_residual: bool):

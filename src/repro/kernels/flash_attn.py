@@ -36,10 +36,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, sk: int, bkv: int,
 
     def body(j, carry):
         m, l, acc = carry
-        # Size-1 dslice instead of a bare int index: jax 0.4.37's interpret-
-        # mode discharge rule rejects scalar int indexers inside pl.load.
-        k = pl.load(k_ref, (pl.dslice(0, 1), pl.dslice(j * bkv, bkv), slice(None)))[0]
-        v = pl.load(v_ref, (pl.dslice(0, 1), pl.dslice(j * bkv, bkv), slice(None)))[0]
+        k = k_ref[0, pl.ds(j * bkv, bkv), :]
+        v = v_ref[0, pl.ds(j * bkv, bkv), :]
         s = q @ k.astype(jnp.float32).T  # (bq, bkv)
         if causal:
             qpos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 0)

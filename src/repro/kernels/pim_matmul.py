@@ -35,12 +35,14 @@ from .epilogue import (
     pad_axis,
     quant_accumulate,
     round_up,
+    split_activations,
     unpack_epilogue_refs,
 )
 
 
-def _mm_kernel(x_ref, w_ref, s_ref, *rest, n_k: int, bits: int,
-               activation: str, has_bias: bool, has_residual: bool):
+def _mm_kernel(*refs, n_x: int, n_k: int, bits: int, activation: str,
+               has_bias: bool, has_residual: bool):
+    x_refs, (w_ref, s_ref), rest = refs[:n_x], refs[n_x:n_x + 2], refs[n_x + 2:]
     o_ref, b_ref, r_ref = unpack_epilogue_refs(rest, has_bias, has_residual)
     k = pl.program_id(2)
 
@@ -49,8 +51,8 @@ def _mm_kernel(x_ref, w_ref, s_ref, *rest, n_k: int, bits: int,
         o_ref[...] = jnp.zeros_like(o_ref)
 
     # Dequantize the weight tile at the VMEM boundary (the 'BRAM port').
-    x = x_ref[...].astype(jnp.float32)
-    o_ref[...] += quant_accumulate(x, w_ref[...], bits)
+    xs = [r[...].astype(jnp.float32) for r in x_refs]
+    o_ref[...] += quant_accumulate(xs, w_ref[...], bits)
 
     @pl.when(k == n_k - 1)
     def _flush():
@@ -112,17 +114,19 @@ def pim_matmul(
     x = pad_axis(pad_axis(x, 1, k_pad), 0, m_pad)
     scale = pad_axis(scale, 1, n_pad)
 
-    x_spec = pl.BlockSpec((bm, bk), lambda i, j, k: (i, k))
+    xs = split_activations(x, bits)
+    bkx = bk if bits == 8 else bk // 2  # K columns per activation block
     if bits == 8:
         w_codes = pad_axis(pad_axis(w_codes, 0, k_pad), 1, n_pad)
-        w_spec = pl.BlockSpec((bk, bn), lambda i, j, k: (k, j))
     else:
         w_codes = pad_axis(pad_axis(w_codes, 0, k_pad // 2), 1, n_pad)
-        w_spec = pl.BlockSpec((bk // 2, bn), lambda i, j, k: (k, j))
-    s_spec = pl.BlockSpec((1, bn), lambda i, j, k: (0, j))
 
-    in_specs = [x_spec, w_spec, s_spec]
-    operands = [x, w_codes, scale]
+    in_specs = [pl.BlockSpec((bm, bkx), lambda i, j, k: (i, k)) for _ in xs]
+    in_specs += [
+        pl.BlockSpec((bkx, bn), lambda i, j, k: (k, j)),
+        pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
+    ]
+    operands = [*xs, w_codes, scale]
     ep_specs, ep_ops = build_epilogue_inputs(
         bias, residual, m=m, n=n, m_pad=m_pad, n_pad=n_pad, bm=bm, bn=bn,
         row_map=lambda i, j, k: (0, j), tile_map=lambda i, j, k: (i, j))
@@ -131,7 +135,7 @@ def pim_matmul(
 
     out = pl.pallas_call(
         functools.partial(
-            _mm_kernel, n_k=n_k, bits=bits, activation=activation,
+            _mm_kernel, n_x=len(xs), n_k=n_k, bits=bits, activation=activation,
             has_bias=bias is not None, has_residual=residual is not None,
         ),
         grid=grid,

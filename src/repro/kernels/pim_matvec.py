@@ -31,14 +31,16 @@ from .epilogue import (
     pad_axis,
     quant_accumulate,
     round_up,
+    split_activations,
     unpack_epilogue_refs,
 )
 
 MAX_M = 8  # decode-shaped: one f32 sublane tile of activation rows
 
 
-def _mv_kernel(x_ref, w_ref, s_ref, *rest, n_k: int, bits: int,
-               activation: str, has_bias: bool, has_residual: bool):
+def _mv_kernel(*refs, n_x: int, n_k: int, bits: int, activation: str,
+               has_bias: bool, has_residual: bool):
+    x_refs, (w_ref, s_ref), rest = refs[:n_x], refs[n_x:n_x + 2], refs[n_x + 2:]
     o_ref, b_ref, r_ref = unpack_epilogue_refs(rest, has_bias, has_residual)
     k = pl.program_id(1)
 
@@ -46,8 +48,8 @@ def _mv_kernel(x_ref, w_ref, s_ref, *rest, n_k: int, bits: int,
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    x = x_ref[...].astype(jnp.float32)  # (8, bk)
-    o_ref[...] += quant_accumulate(x, w_ref[...], bits)
+    xs = [r[...].astype(jnp.float32) for r in x_refs]  # (8, bk) or 2x (8, bk/2)
+    o_ref[...] += quant_accumulate(xs, w_ref[...], bits)
 
     @pl.when(k == n_k - 1)
     def _flush():
@@ -105,22 +107,22 @@ def pim_matvec(
     bias = normalize_bias(bias, n)
     x = pad_axis(pad_axis(x, 1, k_pad), 0, MAX_M)
     scale = pad_axis(scale, 1, n_pad)
+    xs = split_activations(x, bits)
+    bkx = bk if bits == 8 else bk // 2  # K columns per activation block
     if bits == 8:
         w_codes = pad_axis(pad_axis(w_codes, 0, k_pad), 1, n_pad)
-        w_spec = pl.BlockSpec((bk, bn), lambda j, k: (k, j))
     else:
         w_codes = pad_axis(pad_axis(w_codes, 0, k_pad // 2), 1, n_pad)
-        w_spec = pl.BlockSpec((bk // 2, bn), lambda j, k: (k, j))
 
     n_k = k_pad // bk
     grid = (n_pad // bn, n_k)
 
-    in_specs = [
-        pl.BlockSpec((MAX_M, bk), lambda j, k: (0, k)),
-        w_spec,
+    in_specs = [pl.BlockSpec((MAX_M, bkx), lambda j, k: (0, k)) for _ in xs]
+    in_specs += [
+        pl.BlockSpec((bkx, bn), lambda j, k: (k, j)),
         pl.BlockSpec((1, bn), lambda j, k: (0, j)),
     ]
-    operands = [x, w_codes, scale]
+    operands = [*xs, w_codes, scale]
     ep_specs, ep_ops = build_epilogue_inputs(
         bias, residual, m=m, n=n, m_pad=MAX_M, n_pad=n_pad, bm=MAX_M, bn=bn,
         row_map=lambda j, k: (0, j), tile_map=lambda j, k: (0, j))
@@ -129,7 +131,7 @@ def pim_matvec(
 
     out = pl.pallas_call(
         functools.partial(
-            _mv_kernel, n_k=n_k, bits=bits, activation=activation,
+            _mv_kernel, n_x=len(xs), n_k=n_k, bits=bits, activation=activation,
             has_bias=bias is not None, has_residual=residual is not None,
         ),
         grid=grid,

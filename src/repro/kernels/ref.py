@@ -24,8 +24,9 @@ def pim_matvec_ref(
     activation: str = "none",
     residual=None,
 ) -> jnp.ndarray:
-    """Oracle for kernels.pim_matvec: unscaled code matmul, then the same
-    fused-epilogue order (scale [+ bias] -> activation [+ residual])."""
+    """Oracle for kernels.pim_matvec, and for kernels.pim_matmul at any row
+    count: unscaled code matmul, then the same fused-epilogue order
+    (scale [+ bias] -> activation [+ residual])."""
     w = w_codes.astype(jnp.float32) if bits == 8 else _unpack_int4(w_codes)
     acc = jnp.dot(x.astype(jnp.float32), w, preferred_element_type=jnp.float32)
     bias = None if bias is None else jnp.asarray(bias, jnp.float32).reshape(1, -1)

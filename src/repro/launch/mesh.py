@@ -6,16 +6,14 @@ touches jax device state (smoke tests must keep seeing 1 CPU device).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
-def _axis_kwargs(n_axes: int) -> dict:
-    """axis_types=Auto where the installed jax has it (>= 0.4.38); older
-    jax only has Auto behavior, so no kwarg is needed."""
-    try:
-        from jax.sharding import AxisType
-    except ImportError:
-        return {}
-    return {"axis_types": (AxisType.Auto,) * n_axes}
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis Auto (GSPMD-propagated sharding),
+    the mode the sharding rules in ``launch.sharding`` are written for."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -23,16 +21,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod: 2x16x16 = 512 chips (pod, data, model)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_kwargs(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_mesh_from_plan(plan):
     """Mesh for an ElasticPlan (runtime.plan_elastic_remesh)."""
-    return jax.make_mesh(
-        plan.shape,
-        ("pod", "data", "model")[-len(plan.shape):],
-        **_axis_kwargs(len(plan.shape)),
-    )
+    return make_mesh(plan.shape, ("pod", "data", "model")[-len(plan.shape):])
 
 
 def data_axes(mesh) -> tuple:

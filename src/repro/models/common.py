@@ -1,7 +1,6 @@
 """Shared functional building blocks: init, norms, RoPE, PIM-aware linear."""
 from __future__ import annotations
 
-import math
 from typing import Optional
 
 import jax
@@ -21,14 +20,16 @@ def split_keys(key, n):
 
 
 # ------------------------------------------------------------ PIM linear ----
-# Decode-shaped (M <= MATVEC_MAX_M rows) quantized matmuls can route through
-# the epilogue-fused kernels.pim_matvec instead of the XLA overlay path:
+# Quantized 2-D linears can route through the epilogue-fused PIM kernels
+# instead of the XLA overlay path: kernels.pim_matvec for decode-shaped
+# calls (M <= MATVEC_MAX_M activation rows), kernels.pim_matmul for wider
+# ones (prefill, verify windows):
 #   "auto"  — dispatch only on real TPU (compiled Mosaic; CPU interpret mode
 #             is orders of magnitude slower than XLA, so never auto on CPU)
 #   "force" — dispatch everywhere (interpret mode off-TPU; used by tests)
 #   "off"   — always use the XLA overlay path
 MATVEC_MAX_M = 8
-_MATVEC_DISPATCH = "auto"
+_KERNEL_DISPATCH = "auto"
 
 # Named mesh axis the decode-sharding subsystem (serving.sharded) partitions
 # quantized weights over.  A quantized leaf carrying the "tp" marker holds
@@ -37,33 +38,35 @@ _MATVEC_DISPATCH = "auto"
 TP_AXIS = "model"
 
 
-def set_matvec_dispatch(mode: str) -> str:
-    """Set the pim_matvec dispatch mode; returns the previous mode.
+def set_kernel_dispatch(mode: str) -> str:
+    """Set the PIM kernel dispatch mode; returns the previous mode.
 
     The mode is read at trace time, so cached jitted programs would keep
     their baked-in path — clear the jit caches on a mode change so the next
     call re-traces under the new mode."""
-    global _MATVEC_DISPATCH
+    global _KERNEL_DISPATCH
     if mode not in ("auto", "off", "force"):
-        raise ValueError(f"matvec dispatch must be auto|off|force, got {mode!r}")
-    prev, _MATVEC_DISPATCH = _MATVEC_DISPATCH, mode
+        raise ValueError(f"kernel dispatch must be auto|off|force, got {mode!r}")
+    prev, _KERNEL_DISPATCH = _KERNEL_DISPATCH, mode
     if prev != mode:
         jax.clear_caches()
     return prev
 
 
-def _matvec_enabled() -> bool:
-    if _MATVEC_DISPATCH == "off":
+def _kernels_enabled() -> bool:
+    if _KERNEL_DISPATCH == "off":
         return False
-    if _MATVEC_DISPATCH == "force":
+    if _KERNEL_DISPATCH == "force":
         return True
     return jax.default_backend() == "tpu"
 
 
-def _linear_matvec(x: jnp.ndarray, w: dict, b) -> jnp.ndarray:
-    """Route a decode-shaped quantized linear through kernels.pim_matvec
-    (bias fused into the kernel epilogue — no HBM round-trip)."""
+def _linear_kernel(x: jnp.ndarray, w: dict, b) -> jnp.ndarray:
+    """Route a quantized linear through kernels.pim_matvec (decode-shaped)
+    or kernels.pim_matmul, bias fused into the kernel epilogue — no HBM
+    round-trip."""
     from repro.kernels.ops import _interpret
+    from repro.kernels.pim_matmul import pim_matmul
     from repro.kernels.pim_matvec import pim_matvec
 
     bits = 4 if ("nibbles" in w or "nibbles_odd" in w) else 8
@@ -74,10 +77,9 @@ def _linear_matvec(x: jnp.ndarray, w: dict, b) -> jnp.ndarray:
         # activation column keeps the contraction aligned and contributes 0.
         x2 = jnp.pad(x2, ((0, 0), (0, 1)))
     n = w["codes"].shape[-1]
-    y = pim_matvec(
-        x2, w["codes"], w["scale"].reshape(1, n),
-        bits=bits, bias=b, interpret=_interpret(),
-    )
+    kernel = pim_matvec if x2.shape[0] <= MATVEC_MAX_M else pim_matmul
+    y = kernel(x2, w["codes"], w["scale"].reshape(1, n), bits=bits, bias=b,
+               interpret=_interpret())
     return y.reshape(lead + (n,)).astype(x.dtype)
 
 
@@ -85,37 +87,49 @@ def linear(x: jnp.ndarray, w, b: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Matmul against a dense weight or a PIM-quantized leaf.
 
     A PIM leaf is ``{"codes": int8 (..., K, N), "scale": f32}`` produced by
-    ``serving.quantize_tree``; the dequant happens at the matmul operand (XLA
-    fuses it into the producing fusion — the 'overlay' path).  Decode-shaped
-    calls (<= MATVEC_MAX_M activation rows, 2-D weight) route through the
-    epilogue-fused kernels.pim_matvec (the 'overhaul' path) when the
-    dispatch mode allows it — see ``set_matvec_dispatch``.
+    ``serving.quantize_tree``.  A 2-D leaf routes through the epilogue-fused
+    PIM kernels (the 'overhaul' path: pim_matvec for <= MATVEC_MAX_M
+    activation rows, pim_matmul otherwise) when the dispatch mode allows it
+    — see ``set_kernel_dispatch``.  Otherwise the dequant happens at the
+    matmul operand (XLA fuses it into the producing fusion — the 'overlay'
+    path).
 
     A leaf carrying the ``"tp"`` marker (serving.sharded) holds only this
     device's columns: the contraction runs weight-stationary on the local
-    shard (full K, N/devices outputs — the matvec kernel dispatch applies
-    per-shard), then ONE all-gather of the tiny activation tile along
-    ``TP_AXIS`` reassembles the full output.  Gathering output columns is a
-    pure concatenation, so sharded decode stays bit-identical to
-    single-device decode — a K-sharded psum would reorder the float
-    contraction.  The (replicated) bias is added after the gather.
+    shard (full K, N/devices outputs), adds this device's slice of the
+    (replicated) bias, then ONE all-gather of the tiny activation tile
+    along ``TP_AXIS`` reassembles the full output.  Gathering columns is a
+    pure concatenation, and a K-sharded psum would reorder the float
+    contraction, so sharded decode stays bit-identical to single-device
+    decode as long as every output column is computed exactly as on one
+    device.  The kernels guarantee that for shards at least one tile wide:
+    their tiles, and so each column's contraction order, do not depend on
+    N.  An XLA dot does not: the
+    compiler picks its contraction windows per output width and fusion, so
+    a column shard may sum in another order — which is why the TPU path
+    sends prefill through pim_matmul too.
     """
-    if isinstance(w, dict) and "codes" in w:
-        tp = "tp" in w
-        matvec = (w["codes"].ndim == 2 and _matvec_enabled()
-                  and math.prod(x.shape[:-1]) <= MATVEC_MAX_M)
-        if matvec:
-            y = _linear_matvec(x, w, None if tp else b)
-            if not tp:
-                return y  # bias already fused in the kernel epilogue
-        else:
-            y = x @ _dq_local(w, x.dtype)
-        if tp:
-            y = jax.lax.all_gather(y, TP_AXIS, axis=y.ndim - 1, tiled=True)
-    else:
+    if not (isinstance(w, dict) and "codes" in w):
         y = x @ w
-    if b is not None:
-        y = y + b.astype(y.dtype)
+        return y if b is None else y + b.astype(y.dtype)
+    tp = "tp" in w
+    if tp and b is not None:
+        n_loc = w["codes"].shape[-1]
+        b = jax.lax.dynamic_slice_in_dim(
+            b, jax.lax.axis_index(TP_AXIS) * n_loc, n_loc)
+    if w["codes"].ndim == 2 and _kernels_enabled():
+        y = _linear_kernel(x, w, b)  # bias fused in the kernel epilogue
+    else:
+        y = x @ _dq_local(w, x.dtype)
+        if b is not None:
+            y = y + b.astype(y.dtype)
+    # Pin the output at the activation dtype, as a tp leaf's all-gather
+    # does by itself.  Otherwise the compiler may hand consumers the float32
+    # values from before the rounding (excess precision) on one device but
+    # not across a gather, and sharded greedy tokens would drift.
+    y = jax.lax.optimization_barrier(y)
+    if tp:
+        y = jax.lax.all_gather(y, TP_AXIS, axis=y.ndim - 1, tiled=True)
     return y
 
 

@@ -49,10 +49,17 @@ from .common import (
 )
 
 
-def _stack_init(key, n: int, fn):
+def _stack_init(key, n: int, fn, prepare=None):
+    """``n`` layers of ``fn(key_i)`` stacked on a new leading dim.
+
+    Built one layer at a time (``lax.map``), so the float32 draws behind
+    each layer are the only full-precision temporaries — never a float32
+    copy of the whole stack.  ``prepare`` maps each layer's subtree inside
+    the loop (see ``init_params``)."""
     if n == 0:
         return None
-    return jax.vmap(fn)(jax.random.split(key, n))
+    prepare = prepare or (lambda t: t)
+    return jax.lax.map(lambda k: prepare(fn(k)), jax.random.split(key, n))
 
 
 def _stack_cache(cache, n: int):
@@ -81,7 +88,14 @@ def _scan_cached(stack, caches, x, body):
 
 
 # ---------------------------------------------------------------- init ------
-def init_params(cfg: ModelConfig, key) -> dict:
+def init_params(cfg: ModelConfig, key, *, prepare=None) -> dict:
+    """Random parameters for ``cfg``.
+
+    ``prepare`` (optional) maps every layer's subtree as it is built, one
+    layer at a time inside the stacked init loop, and every unstacked
+    subtree once; values are the same as applying it to the finished tree.
+    ``serving.init_serving_params`` quantizes through it, so a full-width
+    model never holds its full-precision stacks on a device."""
     dtype = dtype_of(cfg.param_dtype)
     k_emb, k_layers, k_extra, k_head = split_keys(key, 4)
     p: dict[str, Any] = {
@@ -94,20 +108,20 @@ def init_params(cfg: ModelConfig, key) -> dict:
     fam = cfg.family
     if fam == "dense":
         p["layers"] = _stack_init(
-            k_layers, cfg.n_layers, lambda k: bk.dense_block_init(k, cfg, dtype)
-        )
+            k_layers, cfg.n_layers, lambda k: bk.dense_block_init(k, cfg, dtype),
+            prepare)
     elif fam == "moe":
         nd = cfg.n_dense_layers
         p["dense_layers"] = _stack_init(
-            k_extra, nd, lambda k: bk.dense_block_init(k, cfg, dtype, d_ff=cfg.d_ff)
-        )
+            k_extra, nd, lambda k: bk.dense_block_init(k, cfg, dtype, d_ff=cfg.d_ff),
+            prepare)
         p["layers"] = _stack_init(
-            k_layers, cfg.n_layers - nd, lambda k: bk.moe_block_init(k, cfg, dtype)
-        )
+            k_layers, cfg.n_layers - nd, lambda k: bk.moe_block_init(k, cfg, dtype),
+            prepare)
     elif fam == "ssm":
         p["layers"] = _stack_init(
-            k_layers, cfg.n_layers, lambda k: bk.ssm_block_init(k, cfg, dtype)
-        )
+            k_layers, cfg.n_layers, lambda k: bk.ssm_block_init(k, cfg, dtype),
+            prepare)
     elif fam == "hybrid":
         g = cfg.n_layers // cfg.attn_every  # groups of ssm layers
         tail = cfg.n_layers - g * cfg.attn_every
@@ -115,12 +129,15 @@ def init_params(cfg: ModelConfig, key) -> dict:
         p["groups"] = _stack_init(
             k1, g,
             lambda k: _stack_init(k, cfg.attn_every,
-                                  lambda kk: bk.ssm_block_init(kk, cfg, dtype)),
+                                  lambda kk: bk.ssm_block_init(kk, cfg, dtype),
+                                  prepare),
         )
         p["tail"] = _stack_init(
-            k2, tail, lambda k: bk.ssm_block_init(k, cfg, dtype)
+            k2, tail, lambda k: bk.ssm_block_init(k, cfg, dtype), prepare
         )
         p["shared_attn"] = bk.dense_block_init(k3, cfg, dtype)  # ONE shared block
+        if prepare is not None:
+            p["shared_attn"] = prepare(p["shared_attn"])
     elif fam == "vlm":
         every = cfg.vision.cross_attn_every
         g = cfg.n_layers // every
@@ -129,22 +146,26 @@ def init_params(cfg: ModelConfig, key) -> dict:
             "self": _stack_init(
                 k1, g,
                 lambda k: _stack_init(k, every - 1,
-                                      lambda kk: bk.dense_block_init(kk, cfg, dtype)),
+                                      lambda kk: bk.dense_block_init(kk, cfg, dtype),
+                                      prepare),
             ),
             "cross": _stack_init(
-                k2, g, lambda k: bk.cross_block_init(k, cfg, dtype)
+                k2, g, lambda k: bk.cross_block_init(k, cfg, dtype), prepare
             ),
         }
     elif fam == "encdec":
         k1, k2 = split_keys(k_layers, 2)
         p["encoder"] = _stack_init(
-            k1, cfg.n_layers, lambda k: bk.dense_block_init(k, cfg, dtype)
-        )
+            k1, cfg.n_layers, lambda k: bk.dense_block_init(k, cfg, dtype),
+            prepare)
         p["decoder"] = _stack_init(
-            k2, cfg.dec_layers, lambda k: _encdec_dec_block_init(k, cfg, dtype)
-        )
+            k2, cfg.dec_layers, lambda k: _encdec_dec_block_init(k, cfg, dtype),
+            prepare)
     else:
         raise ValueError(fam)
+    if prepare is not None:
+        top = {k: p[k] for k in ("embed", "ln_f", "head") if k in p}
+        p.update(prepare(top))
     return p
 
 

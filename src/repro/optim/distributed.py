@@ -15,7 +15,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from .compression import compress_gradients, decompress_gradients
@@ -46,11 +45,8 @@ def compressed_psum_mean(grads, mesh, axis: str = "data"):
 
     leaves, treedef = jax.tree_util.tree_flatten(grads)
     specs = tuple(P(*((None,) * l.ndim)) for l in leaves)
-    # check_rep=False: jax 0.4.37's static replication checker cannot see
-    # through the integer-psum + gathered-scale reconstruction; the outputs
-    # ARE replicated (each shard computes the same weighted sum).
-    out = shard_map(
-        body, mesh=mesh, in_specs=(specs,), out_specs=specs, check_rep=False,
+    out = jax.shard_map(
+        body, mesh=mesh, in_specs=(specs,), out_specs=specs,
     )(leaves)
     return jax.tree_util.tree_unflatten(treedef, out)
 
@@ -85,11 +81,11 @@ def dp_train_step_factory(loss_fn, mesh, axis: str = "data"):
             lambda l: P(*((axis,) + (None,) * (jnp.ndim(l) - 1))), batch
         )
         rspec = jax.tree.map(lambda l: P(*((None,) * jnp.ndim(l))), params)
-        return shard_map(
+        return jax.shard_map(
             per_shard, mesh=mesh,
             in_specs=(pspec, bspec, rspec),
             out_specs=(pspec, rspec, P()),
-            check_rep=False,
+            check_vma=False,
         )(params, batch, residual)
 
     return step

@@ -38,7 +38,9 @@ def quantize_symmetric(w: jnp.ndarray, bits: int = 8, axis: int = 0) -> Quantize
     """
     qmax = 2 ** (bits - 1) - 1
     amax = jnp.max(jnp.abs(w), axis=axis, keepdims=True)
-    scale = jnp.where(amax > 0, amax / qmax, 1.0).astype(jnp.float32)
+    # Multiply by the reciprocal: a compiler rewrites division by a constant
+    # into exactly this, so eager and jitted quantization agree bit for bit.
+    scale = jnp.where(amax > 0, amax * (1.0 / qmax), 1.0).astype(jnp.float32)
     codes = jnp.clip(jnp.round(w / scale), -qmax - 1, qmax).astype(jnp.int8)
     return QuantizedTensor(codes=codes, scale=scale, bits=bits)
 

@@ -62,6 +62,7 @@ from .engine import (
     ContinuousBatchingEngine,
     Request,
     ServingEngine,
+    init_serving_params,
     mask_after_stop,
     pim_bytes,
     quantize_tree,
@@ -90,7 +91,7 @@ from .speculative import SpecConfig, propose_ngram
 
 __all__ = [
     "ServingEngine", "ContinuousBatchingEngine", "Request", "quantize_tree",
-    "pim_bytes", "mask_after_stop", "make_decode_mesh",
+    "init_serving_params", "pim_bytes", "mask_after_stop", "make_decode_mesh",
     "shard_quantized_tree", "tree_pspecs", "SpecConfig", "propose_ngram",
     "acceptance_probs", "residual_dist", "rejection_sample", "sample_rows",
     "warp_logits", "draw_keys",

@@ -129,7 +129,6 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
@@ -137,6 +136,7 @@ from repro.models import (
     decode_step,
     init_cache,
     init_paged_cache,
+    init_params,
     prefill,
     verify_step,
 )
@@ -164,7 +164,12 @@ from repro.serving.sampling import (
     sample_rows,
     warp_logits,
 )
-from repro.serving.sharded import shard_quantized_tree, tree_pspecs
+from repro.serving.sharded import (
+    decode_shardings,
+    is_qleaf,
+    shard_quantized_tree,
+    tree_pspecs,
+)
 from repro.serving.speculative import SpecConfig
 
 # Leaves that stay dense: norms/gains/biases/scalars, router (accuracy-
@@ -200,9 +205,41 @@ def _should_quantize(path, leaf) -> bool:
     return leaf.shape[-1] >= 8 and leaf.shape[-2] >= 8
 
 
-def quantize_tree(params, bits: int = 8):
+def _quantize_matrix(w, bits: int) -> dict:
+    """One (K, N) weight -> its PIM leaf dict (see ``quantize_tree``)."""
+    q = quantize_symmetric(w.astype(jnp.float32), bits=bits, axis=-2)
+    if bits != 4:
+        return {"codes": q.codes, "scale": q.scale}
+    codes = q.codes
+    odd = codes.shape[-2] % 2
+    if odd:
+        codes = jnp.concatenate([codes, jnp.zeros_like(codes[:1])], axis=0)
+    lo = codes[0::2] & 0xF
+    hi = codes[1::2] & 0xF
+    return {"codes": (lo | (hi << 4)).astype(jnp.int8), "scale": q.scale}
+
+
+def _quantize_leaf(leaf, bits: int) -> dict:
+    """Quantize the last two dims of ``leaf``, one matrix at a time over
+    any leading stack dims (``lax.map``), so the float32 working copy is
+    one layer's matrix, never the whole stack."""
+    lead = leaf.shape[:-2]
+    if lead:
+        flat = leaf.reshape((-1,) + leaf.shape[-2:])
+        out = jax.lax.map(lambda w: _quantize_matrix(w, bits), flat)
+        out = jax.tree.map(lambda a: a.reshape(lead + a.shape[1:]), out)
+    else:
+        out = _quantize_matrix(leaf, bits)
+    if bits == 4:
+        marker = "nibbles_odd" if leaf.shape[-2] % 2 else "nibbles"
+        out[marker] = jnp.zeros(lead, jnp.int8)
+    return out
+
+
+def quantize_tree(params, bits: int = 8, *, mesh=None):
     """Convert matmul weights to PIM storage. Quantizes the last two dims
-    (per-output-channel scales), keeping any leading stack dims.
+    (per-output-channel scales), keeping any leading stack dims.  Leaves
+    that are already quantized pass through unchanged.
 
     bits=4 packs two codes per byte along the K (contraction) dim — the
     storage actually shipped to HBM; ``models.common.linear``/``dq`` unpack
@@ -210,27 +247,46 @@ def quantize_tree(params, bits: int = 8):
     and flagged with the ``nibbles_odd`` marker key so ``dq``/``weight_shape``
     drop the pad row statically (the seed silently fell back to INT8 storage
     for odd K).  The marker leaf ("nibbles" / "nibbles_odd") carries any
-    leading stack dims so ``lax.scan`` can slice it."""
+    leading stack dims so ``lax.scan`` can slice it.
+
+    ``mesh``: quantize under one ``jit`` whose outputs land directly on
+    their decode placement over the mesh (``serving.sharded``), instead of
+    on the default device first."""
+    if mesh is not None:
+        fn = functools.partial(quantize_tree, bits=bits)
+        out = decode_shardings(jax.eval_shape(fn, params), mesh)
+        return jax.jit(fn, out_shardings=out)(params)
 
     def conv(path, leaf):
-        if not _should_quantize(path, leaf):
+        if is_qleaf(leaf) or not _should_quantize(path, leaf):
             return leaf
-        q = quantize_symmetric(leaf.astype(jnp.float32), bits=bits, axis=-2)
-        if bits == 4:
-            codes = q.codes
-            odd = codes.shape[-2] % 2
-            if odd:
-                codes = jnp.concatenate(
-                    [codes, jnp.zeros_like(codes[..., :1, :])], axis=-2)
-            lo = codes[..., 0::2, :] & 0xF
-            hi = codes[..., 1::2, :] & 0xF
-            packed = (lo | (hi << 4)).astype(jnp.int8)
-            marker = "nibbles_odd" if odd else "nibbles"
-            return {"codes": packed, "scale": q.scale,
-                    marker: jnp.zeros(packed.shape[:-2], jnp.int8)}
-        return {"codes": q.codes, "scale": q.scale}
+        return _quantize_leaf(leaf, bits)
 
-    return jax.tree_util.tree_map_with_path(conv, params)
+    return jax.tree_util.tree_map_with_path(conv, params, is_leaf=is_qleaf)
+
+
+def init_serving_params(cfg: ModelConfig, key, *, bits: int, mesh=None):
+    """Random PIM-quantized parameters for ``cfg``, equal to
+    ``quantize_tree(init_params(cfg, key), bits)`` but built for full
+    widths: one ``jit`` draws and quantizes each layer inside the stacked
+    init loop (``models.init_params(prepare=)``), so no full-precision
+    stack, and no float32 copy of one, ever exists on a device.  With a
+    ``mesh`` the outputs land directly on their decode placement and carry
+    the ``"tp"`` markers (``serving.sharded``); the random draws are the
+    same at any placement."""
+    def prepare(tree):
+        # The barrier pins each weight at its param dtype before it is
+        # quantized: without it the compiler may fuse the draw into the
+        # quantizer and skip the rounding to bfloat16 (excess precision).
+        return quantize_tree(jax.lax.optimization_barrier(tree), bits)
+
+    def build(k):
+        return init_params(cfg, k, prepare=prepare)
+
+    if mesh is None:
+        return jax.jit(build)(key)
+    out = decode_shardings(jax.eval_shape(build, key), mesh)
+    return shard_quantized_tree(jax.jit(build, out_shardings=out)(key), mesh)
 
 
 def pim_bytes(params, per_device: bool = False) -> int:
@@ -345,10 +401,10 @@ def _generate_scan_sharded(params, cfg: ModelConfig, prompt, extras, key,
         return _generate_body(p, cfg, pr, ex, k, t, n_new=n_new,
                               max_seq=max_seq, greedy=greedy, top_k=top_k)
 
-    return shard_map(
+    return jax.shard_map(
         f, mesh=mesh,
         in_specs=(tree_pspecs(params), P(), P(), P(), P()),
-        out_specs=P(), check_rep=False,
+        out_specs=P(), check_vma=False,
     )(params, prompt, extras, key, temperature)
 
 
@@ -367,7 +423,8 @@ class ServingEngine:
                  draft_params=None, draft_pim_bits: int = 0):
         self.cfg = cfg
         self.mesh = mesh
-        params = quantize_tree(params, pim_bits) if pim_bits else params
+        if pim_bits:
+            params = quantize_tree(params, pim_bits, mesh=mesh)
         if mesh is not None:
             params = shard_quantized_tree(params, mesh)
         self.params = params
@@ -595,6 +652,12 @@ class Request:
     rid: Optional[int] = None      # sampled-draw key id (default: trace index)
 
 
+def _nonfinite_rows(logits, live=True):
+    """How many (live) rows of ``logits`` (..., V) hold a NaN or Inf."""
+    bad = ~jnp.all(jnp.isfinite(logits), axis=-1)
+    return jnp.sum(bad & live, dtype=jnp.int32)
+
+
 def _admit_body(params, cfg: ModelConfig, cache, prompt, length, slot, pages,
                 rid, key, temperature, extras, *, greedy: bool, top_k: int):
     """Admit one request: batch-1 single-pass prefill written STRAIGHT into
@@ -604,7 +667,8 @@ def _admit_body(params, cfg: ModelConfig, cache, prompt, length, slot, pages,
     true prompt end with the request's draw-0 key (``key`` is the serve
     call's BASE key; recompute preemption re-derives the same key and
     replays the same token).  Compiled once per padded prompt length (a
-    page multiple, carried by ``prompt``'s shape)."""
+    page multiple, carried by ``prompt``'s shape).  Also returns 1 when
+    the sampled logits row holds a NaN or Inf, else 0."""
     logits, cache = prefill(params, cfg, prompt, cache, extras, length=length,
                             pages=pages, slot=slot)
     lg = jax.lax.dynamic_index_in_dim(logits, length - 1, axis=1,
@@ -612,7 +676,7 @@ def _admit_body(params, cfg: ModelConfig, cache, prompt, length, slot, pages,
     tok0 = sample_rows(
         lg, None if greedy else draw_keys(key, rid[None], 0, TAG_TOKEN),
         greedy=greedy, temperature=temperature, top_k=top_k)[0]
-    return cache, tok0
+    return cache, tok0, _nonfinite_rows(lg)
 
 
 _admit_prefill = functools.partial(
@@ -636,10 +700,10 @@ def _admit_prefill_sharded(params, cfg: ModelConfig, cache, prompt, length,
         return _admit_body(p, cfg, c, pr, ln, sl, pg, ri, k, t, ex,
                            greedy=greedy, top_k=top_k)
 
-    return shard_map(
+    return jax.shard_map(
         f, mesh=mesh,
         in_specs=(tree_pspecs(params),) + (P(),) * 9,
-        out_specs=P(), check_rep=False,
+        out_specs=P(), check_vma=False,
     )(params, cache, prompt, length, slot, pages, rid, key, temperature,
       extras)
 
@@ -724,10 +788,10 @@ def _tail_verify_sharded(params, cfg: ModelConfig, cache, tokens, pos, slot,
                                  greedy=greedy, top_k=top_k,
                                  page_size=page_size)
 
-    return shard_map(
+    return jax.shard_map(
         f, mesh=mesh,
         in_specs=(tree_pspecs(params),) + (P(),) * 10,
-        out_specs=P(), check_rep=False,
+        out_specs=P(), check_vma=False,
     )(params, cache, tokens, pos, slot, rid, sample_at, key, temperature,
       extras)
 
@@ -744,10 +808,11 @@ def _decode_chunk_body(params, cfg: ModelConfig, cache, tok, pos, n_out, done,
     masked; the host retires/admits at the chunk boundary.  Sampled draws
     are keyed per slot by ``(key, rid, n_out)`` — the same stream the
     fixed-batch engine consumes — so a request's tokens never depend on
-    slot assignment or chunk boundaries."""
+    slot assignment or chunk boundaries.  Also returns how many live
+    (slot, step) logits rows held a NaN or Inf."""
 
     def body(carry, _):
-        tok, cache, pos, n_out, done = carry
+        tok, cache, pos, n_out, done, bad = carry
         lg, cache = decode_step(params, cfg, tok, cache, pos, extras,
                                 page_size=page_size)
         nxt = sample_rows(
@@ -755,17 +820,19 @@ def _decode_chunk_body(params, cfg: ModelConfig, cache, tok, pos, n_out, done,
             None if greedy else draw_keys(key, rids, n_out, TAG_TOKEN),
             greedy=greedy, temperature=temperature, top_k=top_k)
         live = ~done
+        bad = bad + _nonfinite_rows(lg[:, -1, :], live)
         emit = jnp.where(live, nxt, jnp.int32(pad_id))
         pos = jnp.where(live, pos + 1, pos)
         n_out = jnp.where(live, n_out + 1, n_out)
         hit = jnp.any(emit[:, None] == stops, axis=1)
         done = done | (live & hit) | (n_out >= max_new)
-        return (emit[:, None], cache, pos, n_out, done), (emit, live)
+        return (emit[:, None], cache, pos, n_out, done, bad), (emit, live)
 
     carry, (emits, lives) = jax.lax.scan(
-        body, (tok, cache, pos, n_out, done), None, length=chunk)
-    tok, cache, pos, n_out, done = carry
-    return cache, tok, pos, n_out, done, emits, lives
+        body, (tok, cache, pos, n_out, done, jnp.int32(0)), None,
+        length=chunk)
+    tok, cache, pos, n_out, done, bad = carry
+    return cache, tok, pos, n_out, done, emits, lives, bad
 
 
 _decode_chunk = functools.partial(
@@ -794,10 +861,10 @@ def _decode_chunk_sharded(params, cfg: ModelConfig, cache, tok, pos, n_out,
                                   t, ex, chunk=chunk, page_size=page_size,
                                   greedy=greedy, top_k=top_k, pad_id=pad_id)
 
-    return shard_map(
+    return jax.shard_map(
         f, mesh=mesh,
         in_specs=(tree_pspecs(params),) + (P(),) * 11,
-        out_specs=P(), check_rep=False,
+        out_specs=P(), check_vma=False,
     )(params, cache, tok, pos, n_out, done, rids, max_new, stops, key,
       temperature, extras)
 
@@ -866,7 +933,8 @@ class ContinuousBatchingEngine:
                     "draft-model speculation is single-device (the draft "
                     "tree is not mesh-distributed); use mode='ngram' on a "
                     "mesh")
-        params = quantize_tree(params, pim_bits) if pim_bits else params
+        if pim_bits:
+            params = quantize_tree(params, pim_bits, mesh=mesh)
         if mesh is not None:
             params = shard_quantized_tree(params, mesh)
         self.params = params
@@ -932,6 +1000,9 @@ class ContinuousBatchingEngine:
         self.prefix_hit_tokens = 0  # prompt tokens served from aliased pages
         self.prefill_tokens = 0     # prompt tokens actually computed
         self.cow_forks = 0          # copy-on-write page forks
+        # logits rows holding a NaN or Inf, over admit prefills and plain
+        # decode-chunk steps of live slots; 0 for a healthy model
+        self.nonfinite_logits = 0
         self.peak_pages_in_use = 0
         self.preemptions = 0
         self.spec_emitted = 0  # tokens emitted by speculative verify windows
@@ -1177,16 +1248,17 @@ class ContinuousBatchingEngine:
             admit = (_admit_prefill if self.mesh is None
                      else functools.partial(_admit_prefill_sharded,
                                             mesh=self.mesh))
-            self._cache, tok0 = admit(
+            self._cache, tok0, bad = admit(
                 self.params, self.cfg, self._cache, jnp.asarray(prompt),
                 jnp.int32(L), jnp.int32(slot), jnp.asarray(pages, jnp.int32),
                 jnp.int32(rid), self._key, jnp.float32(temperature), ex1,
                 greedy=bool(greedy), top_k=int(top_k))
+            self.nonfinite_logits += int(bad)
             if self._draft_mode:
                 # Prefill the draft pool's copy of the prompt into the SAME
                 # page ids (its own storage); the draft admit's sample is
                 # discarded — tok0 always comes from the target.
-                self._dcache, _ = _admit_prefill(
+                self._dcache, _, _ = _admit_prefill(
                     self.draft_params, self.draft_cfg, self._dcache,
                     jnp.asarray(prompt), jnp.int32(L), jnp.int32(slot),
                     jnp.asarray(pages, jnp.int32), jnp.int32(rid), self._key,
@@ -1367,6 +1439,25 @@ class ContinuousBatchingEngine:
             requests, greedy=greedy, temperature=temperature, top_k=top_k,
             key=key, policy=policy, chaos=chaos)
         return [r.tokens for r in report.records]
+
+    def decode_chunk_text(self) -> str:
+        """Compiled text of the plain greedy decode-chunk program at this
+        engine's shapes, as ``serve`` runs it — for checking what the
+        compiler put in it (e.g. the ``tpu_custom_call`` of the
+        ``pim_matvec`` kernel).  Call after a ``serve``: the engine's slot
+        state gives the shapes."""
+        args = (self.params, self.cfg, self._cache, jnp.asarray(self._tok),
+                jnp.asarray(self._pos), jnp.asarray(self._n_out),
+                jnp.asarray(self._done), jnp.asarray(self._rids),
+                jnp.asarray(self._max_new), jnp.asarray(self._stops),
+                self._key, jnp.float32(1.0), self._extras_slots)
+        kw = dict(chunk=self.chunk, page_size=self.page_size,
+                  greedy=True, top_k=0, pad_id=self.pad_id)
+        if self.mesh is None:
+            lowered = _decode_chunk.lower(*args, **kw)
+        else:
+            lowered = _decode_chunk_sharded.lower(*args, mesh=self.mesh, **kw)
+        return lowered.compile().as_text()
 
     def _shed(self, records, report, ridx: int, reason: str) -> None:
         rec = records[ridx]
@@ -1868,7 +1959,7 @@ class ContinuousBatchingEngine:
                             else functools.partial(_decode_chunk_sharded,
                                                    mesh=self.mesh))
                     (self._cache, tok, pos, n_out, done, emits,
-                     lives) = step(
+                     lives, bad) = step(
                         self.params, self.cfg, self._cache,
                         jnp.asarray(self._tok),
                         jnp.asarray(self._pos), jnp.asarray(self._n_out),
@@ -1879,6 +1970,7 @@ class ContinuousBatchingEngine:
                         chunk=eff_chunk, page_size=self.page_size,
                         greedy=bool(greedy), top_k=int(top_k),
                         pad_id=self.pad_id)
+                    self.nonfinite_logits += int(bad)
             except (ChunkFault, EngineCrash):
                 raise
             except Exception as e:

@@ -22,7 +22,7 @@ Layout (one rule, every leaf):
 Inside ``shard_map`` the marker drives the collectives:
 
 * ``models.common.linear`` contracts the local shard weight-stationary
-  (the ``set_matvec_dispatch`` kernel path applies per-shard) and
+  (the ``set_kernel_dispatch`` kernel path applies per-shard) and
   all-gathers the output columns — a pure concatenation, so sharded greedy
   decode is TOKEN-IDENTICAL to the single-device engines;
 * einsum consumers (MoE expert stacks, MLA absorbed W_uk/W_uv) go through
@@ -65,12 +65,45 @@ def make_decode_mesh(n_devices: Optional[int] = None,
     return Mesh(np.asarray(devs[:n]), (axis,))
 
 
-def _is_qleaf(tree) -> bool:
+def is_qleaf(tree) -> bool:
+    """A ``quantize_tree`` leaf dict (codes/scale + optional markers)."""
     return isinstance(tree, dict) and "codes" in tree
 
 
 def _last_dim_spec(ndim: int, axis: str) -> P:
     return P(*((None,) * (ndim - 1) + (axis,)))
+
+
+def _split_over(names, codes_shape, size: int, axis: str) -> bool:
+    """Whether a quantized leaf at ``names`` splits its output dim over a
+    ``size``-wide ``axis`` (rule-shardable and divisible)."""
+    spec = decode_partition_spec(names, len(codes_shape), axis)
+    n_out = codes_shape[-1]
+    return axis in spec and n_out % size == 0 and n_out >= size
+
+
+def decode_shardings(params, mesh: Mesh, axis: str = TP_AXIS):
+    """The ``NamedSharding`` of every leaf of a quantized tree (arrays or
+    ``jax.eval_shape`` shapes) in its decode placement: codes and scale of
+    a splittable quantized leaf over ``axis`` on their last dim, everything
+    else replicated.  Lets a ``jit`` write its outputs straight into place
+    (``serving.init_serving_params``, ``quantize_tree(mesh=)``)."""
+    size = mesh.shape[axis]
+
+    def conv(tree, names):
+        if tree is None:
+            return None
+        if is_qleaf(tree):
+            tp = _split_over(names, tree["codes"].shape, size, axis)
+            return {k: NamedSharding(
+                        mesh, _last_dim_spec(v.ndim, axis)
+                        if tp and k in ("codes", "scale") else P())
+                    for k, v in tree.items()}
+        if isinstance(tree, dict):
+            return {k: conv(v, names + [k]) for k, v in tree.items()}
+        return NamedSharding(mesh, P())
+
+    return conv(params, [])
 
 
 def shard_quantized_tree(params, mesh: Mesh, axis: str = TP_AXIS):
@@ -80,9 +113,10 @@ def shard_quantized_tree(params, mesh: Mesh, axis: str = TP_AXIS):
     Shardable quantized leaves (``quant.decode_partition_spec``) whose
     output dim divides the axis get codes+scale split along their last dim
     and a ``"tp"`` marker leaf added; every other leaf is replicated.  All
-    leaves are ``device_put`` with their ``NamedSharding``, so per-device
-    HBM holds only its shard and ``pim_bytes(..., per_device=True)``
-    reports the split.
+    leaves are ``device_put`` with their ``NamedSharding``
+    (``decode_shardings``; a leaf already in place is not copied), so
+    per-device HBM holds only its shard and
+    ``pim_bytes(..., per_device=True)`` reports the split.
 
     Raises if a multi-device mesh ends up distributing NOTHING (e.g. a
     dense tree passed without ``pim_bits``): silently replicating every
@@ -91,37 +125,26 @@ def shard_quantized_tree(params, mesh: Mesh, axis: str = TP_AXIS):
     size = mesh.shape[axis]
     n_marked = 0
 
-    def put(leaf, spec: P):
-        return jax.device_put(leaf, NamedSharding(mesh, spec))
-
-    def conv(tree, names):
+    def conv(tree, shardings):
         nonlocal n_marked
         if tree is None:
             return None
-        if _is_qleaf(tree):
-            nd = tree["codes"].ndim
-            spec = decode_partition_spec(names, nd, axis)
-            n_out = tree["codes"].shape[-1]
-            tp = (axis in spec and n_out % size == 0 and n_out >= size)
-            n_marked += tp
-            out = {}
-            for k, v in tree.items():
-                if tp and k in ("codes", "scale"):
-                    out[k] = put(v, _last_dim_spec(v.ndim, axis))
-                else:
-                    out[k] = put(v, P())
-            if tp:
+        if is_qleaf(tree):
+            out = {k: jax.device_put(v, shardings[k]) for k, v in tree.items()}
+            if axis in shardings["codes"].spec:
+                n_marked += 1
                 # Like the int4 "nibbles" markers, the tag carries the
                 # leading stack dims so lax.scan over stacked layers can
                 # slice it alongside codes/scale.
-                out["tp"] = put(jnp.zeros(tree["codes"].shape[:-2], jnp.int8),
-                                P())
+                out["tp"] = jax.device_put(
+                    jnp.zeros(tree["codes"].shape[:-2], jnp.int8),
+                    NamedSharding(mesh, P()))
             return out
         if isinstance(tree, dict):
-            return {k: conv(v, names + [k]) for k, v in tree.items()}
-        return put(tree, P())
+            return {k: conv(v, shardings[k]) for k, v in tree.items()}
+        return jax.device_put(tree, shardings)
 
-    out = conv(params, [])
+    out = conv(params, decode_shardings(params, mesh, axis))
     if size > 1 and n_marked == 0:
         raise ValueError(
             f"nothing to distribute over the {size}-device '{axis}' mesh: "
@@ -139,7 +162,7 @@ def tree_pspecs(params, axis: str = TP_AXIS):
     def conv(tree):
         if tree is None:
             return None
-        if _is_qleaf(tree):
+        if is_qleaf(tree):
             tp = "tp" in tree
             return {
                 k: (_last_dim_spec(v.ndim, axis)

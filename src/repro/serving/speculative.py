@@ -145,7 +145,6 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
@@ -672,10 +671,10 @@ def _spec_generate_sharded(params, cfg: ModelConfig, prompt, extras, key,
             accept=accept, typical_eps=typical_eps,
             typical_delta=typical_delta)
 
-    return shard_map(
+    return jax.shard_map(
         f, mesh=mesh,
         in_specs=(tree_pspecs(params), P(), P(), P(), P()),
-        out_specs=(P(), P(), P()), check_rep=False,
+        out_specs=(P(), P(), P()), check_vma=False,
     )(params, prompt, extras, key, temperature)
 
 
@@ -777,10 +776,10 @@ def _spec_tree_generate_sharded(params, cfg: ModelConfig, prompt, extras, key,
             p, cfg, pr, ex, ky, t, n_new=n_new, max_seq=max_seq, fan=fan,
             depth=depth, ngram_n=ngram_n, greedy=greedy, top_k=top_k)
 
-    return shard_map(
+    return jax.shard_map(
         f, mesh=mesh,
         in_specs=(tree_pspecs(params), P(), P(), P(), P()),
-        out_specs=(P(), P(), P()), check_rep=False,
+        out_specs=(P(), P(), P()), check_vma=False,
     )(params, prompt, extras, key, temperature)
 
 
@@ -911,10 +910,10 @@ def _spec_chunk_sharded(params, cfg: ModelConfig, cache, tok, pos, n_out,
             typical_delta=typical_delta)
         return c, tk, ps_, no, dn, hs, wc, em, emits, ms
 
-    return shard_map(
+    return jax.shard_map(
         f, mesh=mesh,
         in_specs=(tree_pspecs(params),) + (P(),) * 14,
-        out_specs=P(), check_rep=False,
+        out_specs=P(), check_vma=False,
     )(params, cache, tok, pos, n_out, done, hist, wctr, ema, rids, max_new,
       stops, key, temperature, extras)
 
@@ -1015,9 +1014,9 @@ def _spec_tree_chunk_sharded(params, cfg: ModelConfig, cache, tok, pos,
             chunk=chunk, page_size=page_size, fan=fan, depth=depth,
             ngram_n=ngram_n, pad_id=pad_id, greedy=greedy, top_k=top_k)
 
-    return shard_map(
+    return jax.shard_map(
         f, mesh=mesh,
         in_specs=(tree_pspecs(params),) + (P(),) * 13,
-        out_specs=P(), check_rep=False,
+        out_specs=P(), check_vma=False,
     )(params, cache, tok, pos, n_out, done, hist, wctr, rids, max_new, stops,
       key, temperature, extras)

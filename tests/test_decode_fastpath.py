@@ -11,7 +11,7 @@ from repro.kernels import ref
 from repro.kernels.pim_matmul import pim_matmul
 from repro.kernels.pim_matvec import pim_matvec
 from repro.models import decode_step, forward, init_cache, init_params, prefill
-from repro.models.common import dq, linear, set_matvec_dispatch, weight_shape
+from repro.models.common import dq, linear, set_kernel_dispatch, weight_shape
 from repro.quant import pack_int4, quantize_symmetric
 from repro.serving import ServingEngine, quantize_tree
 
@@ -195,25 +195,46 @@ def test_pim_matmul_pad_to_tile_and_epilogue():
 
 
 # --------------------------------------------------- linear kernel dispatch -
+@pytest.mark.parametrize("rows", [2, 12])
 @pytest.mark.parametrize("bits,kdim", [(8, 64), (4, 64), (4, 33)])
-def test_linear_dispatches_to_matvec(bits, kdim):
-    """force mode: decode-shaped quantized linear routes through pim_matvec
-    (interpret) and agrees with the XLA overlay path — including the odd-K
-    int4 'nibbles_odd' packing."""
+def test_linear_dispatches_to_matvec(bits, kdim, rows):
+    """force mode: a quantized linear routes through pim_matvec (decode-
+    shaped, 2 rows) or pim_matmul (12 rows), in interpret mode, and agrees
+    with the XLA overlay path — including the odd-K int4 'nibbles_odd'
+    packing."""
     w = {"w": jax.random.normal(jax.random.PRNGKey(2), (kdim, 24))}
     q = quantize_tree(w, bits=bits)["w"]
     assert isinstance(q, dict)
-    x = jax.random.normal(jax.random.PRNGKey(4), (2, 1, kdim))
+    x = jax.random.normal(jax.random.PRNGKey(4), (rows, 1, kdim))
     b = jax.random.normal(jax.random.PRNGKey(6), (24,))
-    prev = set_matvec_dispatch("force")
+    prev = set_kernel_dispatch("force")
     try:
         y_kernel = linear(x, q, b)
-        set_matvec_dispatch("off")
+        set_kernel_dispatch("off")
         y_overlay = linear(x, q, b)
     finally:
-        set_matvec_dispatch(prev)
+        set_kernel_dispatch(prev)
     np.testing.assert_allclose(np.asarray(y_kernel), np.asarray(y_overlay),
                                rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("bits", [8, 4])
+@pytest.mark.parametrize("m", [4, 40])
+def test_kernel_column_shards_bit_identical(m, bits):
+    """A column shard at least one tile wide gives exactly the matching
+    columns of the full product: the kernels' tiles, and so each column's
+    contraction order, do not depend on N.  Sharded decode and prefill rely
+    on it."""
+    x, w = _mk(m, 1024, 1024, seed=11)
+    q = quantize_symmetric(w, bits=bits, axis=0)
+    codes = q.codes if bits == 8 else pack_int4(q.codes)
+    kernel = pim_matvec if m <= 8 else pim_matmul
+    full = np.asarray(kernel(x, codes, q.scale, bits=bits, interpret=True))
+    for j in range(4):
+        cols = slice(j * 256, (j + 1) * 256)
+        part = kernel(x, codes[:, cols], q.scale[:, cols], bits=bits,
+                      interpret=True)
+        np.testing.assert_array_equal(np.asarray(part), full[:, cols])
 
 
 # ----------------------------------------------------- int4 odd-K quantize --

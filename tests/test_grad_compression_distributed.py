@@ -14,10 +14,10 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json
 import jax, jax.numpy as jnp
 import numpy as np
-from repro.launch.mesh import _axis_kwargs
+from repro.launch.mesh import make_mesh
 from repro.optim.distributed import dp_train_step_factory
 
-mesh = jax.make_mesh((8,), ("data",), **_axis_kwargs(1))
+mesh = make_mesh((8,), ("data",))
 W = jax.random.normal(jax.random.PRNGKey(0), (16, 4))
 params = {"w": jnp.zeros((16, 4))}
 x = jax.random.normal(jax.random.PRNGKey(1), (64, 16))
@@ -30,7 +30,7 @@ def loss_fn(p, b):
 step = dp_train_step_factory(loss_fn, mesh, axis="data")
 residual = jax.tree.map(lambda l: jnp.zeros(l.shape, jnp.float32), params)
 losses = []
-# 150 steps (was 60): under jax 0.4.37 the int8 error-feedback exchange
+# 150 steps (was 60): the int8 error-feedback exchange
 # reaches the 100x loss-reduction bar at ~step 100 (trajectory verified
 # monotone: 10.75 -> 0.18 @60 -> 0.042 @100 -> 0.012 @140); the original
 # 60-step budget was tuned on a newer jax and never passed in this image.

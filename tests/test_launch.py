@@ -49,11 +49,11 @@ import json, sys
 import jax
 from repro.configs import get_reduced
 from repro.configs.base import ShapeConfig
-from repro.launch.mesh import _axis_kwargs
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import lower_cell
 from repro.launch.roofline import analyze
 
-mesh = jax.make_mesh((2, 4), ("data", "model"), **_axis_kwargs(2))
+mesh = make_mesh((2, 4), ("data", "model"))
 out = []
 for arch in sys.argv[1].split(","):
     cfg = get_reduced(arch)

@@ -260,7 +260,9 @@ def test_scheduler_stop_at_exactly_max_new():
     cfg, params, prompt, _ = _setup("starcoder2-7b")
     dense = ServingEngine(cfg, params, max_seq=16)
     base = np.asarray(dense.generate(prompt, n_new=6))
-    n = 4
+    # the latest emission (past the first) whose token first appears there,
+    # so the stop's first hit is the final emission
+    n = max(i + 1 for i in range(1, 6) if base[0, i] not in base[0, :i])
     stop = int(base[0, n - 1])
     assert stop not in base[0, : n - 1]  # first hit is the final emission
     eng = ContinuousBatchingEngine(cfg, params, slots=2, max_seq=16,
@@ -300,12 +302,13 @@ def test_fixed_engine_stop_at_exactly_n_new():
     cfg, params, prompt, _ = _setup("starcoder2-7b")
     eng = ServingEngine(cfg, params, max_seq=16)
     base = np.asarray(eng.generate(prompt, n_new=5))
-    stop = int(base[0, -1])
-    if stop in base[0, :-1]:  # ensure LAST position is the first hit
-        pytest.skip("fixture emits the stop earlier; covered elsewhere")
-    got = np.asarray(eng.generate(prompt, n_new=5, stop_tokens=(stop,),
+    # the latest emission whose token first appears there, so the stop's
+    # first hit is the LAST emitted position of an n-token generate
+    n = max(i + 1 for i in range(5) if base[0, i] not in base[0, :i])
+    stop = int(base[0, n - 1])
+    got = np.asarray(eng.generate(prompt, n_new=n, stop_tokens=(stop,),
                                   pad_id=-1))
-    np.testing.assert_array_equal(got[0], base[0])
+    np.testing.assert_array_equal(got[0], base[0, :n])
 
 
 # ------------------------------------------------- page rollback / reuse ----

@@ -266,7 +266,7 @@ from repro.configs import get_reduced
 from repro.models import init_params, encode
 from repro.serving import (ServingEngine, ContinuousBatchingEngine, Request,
                            make_decode_mesh, pim_bytes, tree_pspecs)
-from repro.models.common import set_matvec_dispatch
+from repro.models.common import set_kernel_dispatch
 
 MODE = sys.argv[1]
 ARCHS = sys.argv[2].split(",")
@@ -319,13 +319,13 @@ for arch in ARCHS:
         # the pim_matvec kernel dispatch applies per-shard (one arch is
         # enough: interpret-mode pallas inside the scan is slow)
         if arch == "qwen2-1.5b":
-            set_matvec_dispatch("force")
+            set_kernel_dispatch("force")
             a = ServingEngine(cfg, params, max_seq=16, pim_bits=8)
             b = ServingEngine(cfg, params, max_seq=16, pim_bits=8, mesh=mesh)
             row["matvec_identical"] = bool(np.array_equal(
                 np.asarray(a.generate(prompt, n_new=3)),
                 np.asarray(b.generate(prompt, n_new=3))))
-            set_matvec_dispatch("auto")
+            set_kernel_dispatch("auto")
         # a dense tree over a multi-device mesh distributes nothing: refuse
         try:
             ServingEngine(cfg, params, max_seq=16, mesh=mesh)
