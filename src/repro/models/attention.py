@@ -228,60 +228,62 @@ def attn_prefill(
         positions = jnp.arange(s)
         q = apply_rope(q, positions, rope_theta)
         k = apply_rope(k, positions, rope_theta)
-    k_t = k.transpose(0, 2, 1, 3)  # (B, KV, S, D) — the cache layout
-    v_t = v.transpose(0, 2, 1, 3)
+    with jax.named_scope("kv_write"):
+        k_t = k.transpose(0, 2, 1, 3)  # (B, KV, S, D) — the cache layout
+        v_t = v.transpose(0, 2, 1, 3)
 
-    if pages is not None:
-        n, ps = pages.shape[0], cache["k"].shape[2]
+        if pages is not None:
+            n, ps = pages.shape[0], cache["k"].shape[2]
 
-        def to_pages(t):  # (1, KV, n*ps, ...) -> (n, KV, ps, ...)
-            t = t[0].reshape((t.shape[1], n, ps) + t.shape[3:])
-            return jnp.moveaxis(t, 1, 0)
+            def to_pages(t):  # (1, KV, n*ps, ...) -> (n, KV, ps, ...)
+                t = t[0].reshape((t.shape[1], n, ps) + t.shape[3:])
+                return jnp.moveaxis(t, 1, 0)
 
-        if "k_scale" in cache:
+            if "k_scale" in cache:
+                k_codes, k_sc = _quant_kv(k_t)
+                v_codes, v_sc = _quant_kv(v_t)
+                new_cache = {
+                    "k": cache["k"].at[pages].set(to_pages(k_codes)),
+                    "v": cache["v"].at[pages].set(to_pages(v_codes)),
+                    "k_scale": cache["k_scale"].at[pages].set(to_pages(k_sc)),
+                    "v_scale": cache["v_scale"].at[pages].set(to_pages(v_sc)),
+                }
+                k = (k_codes.astype(x.dtype)
+                     * k_sc[..., None].astype(x.dtype)).transpose(0, 2, 1, 3)
+                v = (v_codes.astype(x.dtype)
+                     * v_sc[..., None].astype(x.dtype)).transpose(0, 2, 1, 3)
+            else:
+                new_cache = {
+                    "k": cache["k"].at[pages].set(
+                        to_pages(k_t).astype(cache["k"].dtype)),
+                    "v": cache["v"].at[pages].set(
+                        to_pages(v_t).astype(cache["v"].dtype)),
+                }
+        elif "k_scale" in cache:
             k_codes, k_sc = _quant_kv(k_t)
             v_codes, v_sc = _quant_kv(v_t)
             new_cache = {
-                "k": cache["k"].at[pages].set(to_pages(k_codes)),
-                "v": cache["v"].at[pages].set(to_pages(v_codes)),
-                "k_scale": cache["k_scale"].at[pages].set(to_pages(k_sc)),
-                "v_scale": cache["v_scale"].at[pages].set(to_pages(v_sc)),
+                "k": jax.lax.dynamic_update_slice(cache["k"], k_codes, (0, 0, 0, 0)),
+                "v": jax.lax.dynamic_update_slice(cache["v"], v_codes, (0, 0, 0, 0)),
+                "k_scale": jax.lax.dynamic_update_slice(cache["k_scale"], k_sc, (0, 0, 0)),
+                "v_scale": jax.lax.dynamic_update_slice(cache["v_scale"], v_sc, (0, 0, 0)),
             }
-            k = (k_codes.astype(x.dtype)
-                 * k_sc[..., None].astype(x.dtype)).transpose(0, 2, 1, 3)
-            v = (v_codes.astype(x.dtype)
-                 * v_sc[..., None].astype(x.dtype)).transpose(0, 2, 1, 3)
+            k = (k_codes.astype(x.dtype) * k_sc[..., None].astype(x.dtype)).transpose(0, 2, 1, 3)
+            v = (v_codes.astype(x.dtype) * v_sc[..., None].astype(x.dtype)).transpose(0, 2, 1, 3)
         else:
             new_cache = {
-                "k": cache["k"].at[pages].set(
-                    to_pages(k_t).astype(cache["k"].dtype)),
-                "v": cache["v"].at[pages].set(
-                    to_pages(v_t).astype(cache["v"].dtype)),
+                "k": jax.lax.dynamic_update_slice(
+                    cache["k"], k_t.astype(cache["k"].dtype), (0, 0, 0, 0)),
+                "v": jax.lax.dynamic_update_slice(
+                    cache["v"], v_t.astype(cache["v"].dtype), (0, 0, 0, 0)),
             }
-    elif "k_scale" in cache:
-        k_codes, k_sc = _quant_kv(k_t)
-        v_codes, v_sc = _quant_kv(v_t)
-        new_cache = {
-            "k": jax.lax.dynamic_update_slice(cache["k"], k_codes, (0, 0, 0, 0)),
-            "v": jax.lax.dynamic_update_slice(cache["v"], v_codes, (0, 0, 0, 0)),
-            "k_scale": jax.lax.dynamic_update_slice(cache["k_scale"], k_sc, (0, 0, 0)),
-            "v_scale": jax.lax.dynamic_update_slice(cache["v_scale"], v_sc, (0, 0, 0)),
-        }
-        k = (k_codes.astype(x.dtype) * k_sc[..., None].astype(x.dtype)).transpose(0, 2, 1, 3)
-        v = (v_codes.astype(x.dtype) * v_sc[..., None].astype(x.dtype)).transpose(0, 2, 1, 3)
-    else:
-        new_cache = {
-            "k": jax.lax.dynamic_update_slice(
-                cache["k"], k_t.astype(cache["k"].dtype), (0, 0, 0, 0)),
-            "v": jax.lax.dynamic_update_slice(
-                cache["v"], v_t.astype(cache["v"].dtype), (0, 0, 0, 0)),
-        }
-    g = n_heads // n_kv
-    qg = q.reshape(b, s, n_kv, g, head_dim)
-    if k.shape[1] > CHUNKED_THRESHOLD:
-        o = _chunked_attention(qg, k, v, causal=True, kv_chunk=kv_chunk)
-    else:
-        o = _direct_attention(qg, k, v, causal=True)
+    with jax.named_scope("attention"):
+        g = n_heads // n_kv
+        qg = q.reshape(b, s, n_kv, g, head_dim)
+        if k.shape[1] > CHUNKED_THRESHOLD:
+            o = _chunked_attention(qg, k, v, causal=True, kv_chunk=kv_chunk)
+        else:
+            o = _direct_attention(qg, k, v, causal=True)
     o = o.reshape(b, s, n_heads * head_dim)
     return linear(o, p["wo"]), new_cache
 
@@ -397,50 +399,53 @@ def attn_decode_paged(
     v_t = v.transpose(0, 2, 1, 3)
     page = jnp.take_along_axis(block_tables, (pos // ps)[:, None], axis=1)[:, 0]
     off = pos % ps
-    quantized = "k_scale" in cache
-    new_cache = dict(cache)
-    if quantized:
-        k_codes, k_sc = _quant_kv(k_t)
-        v_codes, v_sc = _quant_kv(v_t)
-        new_cache["k"] = cache["k"].at[page, :, off, :].set(k_codes[:, :, 0, :])
-        new_cache["v"] = cache["v"].at[page, :, off, :].set(v_codes[:, :, 0, :])
-        new_cache["k_scale"] = cache["k_scale"].at[page, :, off].set(k_sc[:, :, 0])
-        new_cache["v_scale"] = cache["v_scale"].at[page, :, off].set(v_sc[:, :, 0])
-    else:
-        new_cache["k"] = cache["k"].at[page, :, off, :].set(
-            k_t[:, :, 0, :].astype(cache["k"].dtype))
-        new_cache["v"] = cache["v"].at[page, :, off, :].set(
-            v_t[:, :, 0, :].astype(cache["v"].dtype))
-    # Gather the slot's pages: (B, W, KV, ps, D) -> (B, KV, W*ps, D) — the
-    # same head-major layout the dense contraction reads, just assembled from
-    # the block table.  The gather is a transient; only the pool persists.
-    w_pages = block_tables.shape[1]
-    seq = w_pages * ps
+    with jax.named_scope("kv_write"):
+        quantized = "k_scale" in cache
+        new_cache = dict(cache)
+        if quantized:
+            k_codes, k_sc = _quant_kv(k_t)
+            v_codes, v_sc = _quant_kv(v_t)
+            new_cache["k"] = cache["k"].at[page, :, off, :].set(k_codes[:, :, 0, :])
+            new_cache["v"] = cache["v"].at[page, :, off, :].set(v_codes[:, :, 0, :])
+            new_cache["k_scale"] = cache["k_scale"].at[page, :, off].set(k_sc[:, :, 0])
+            new_cache["v_scale"] = cache["v_scale"].at[page, :, off].set(v_sc[:, :, 0])
+        else:
+            new_cache["k"] = cache["k"].at[page, :, off, :].set(
+                k_t[:, :, 0, :].astype(cache["k"].dtype))
+            new_cache["v"] = cache["v"].at[page, :, off, :].set(
+                v_t[:, :, 0, :].astype(cache["v"].dtype))
+    with jax.named_scope("attention"):
+        # Gather the slot's pages: (B, W, KV, ps, D) -> (B, KV, W*ps, D) —
+        # the same head-major layout the dense contraction reads, just
+        # assembled from the block table.  The gather is a transient; only
+        # the pool persists.
+        w_pages = block_tables.shape[1]
+        seq = w_pages * ps
 
-    def gather(pool):
-        g = pool[block_tables]  # (B, W, KV, ps, ...)
-        g = jnp.moveaxis(g, 1, 2)  # (B, KV, W, ps, ...)
-        return g.reshape((b, n_kv, seq) + g.shape[4:])
+        def gather(pool):
+            g = pool[block_tables]  # (B, W, KV, ps, ...)
+            g = jnp.moveaxis(g, 1, 2)  # (B, KV, W, ps, ...)
+            return g.reshape((b, n_kv, seq) + g.shape[4:])
 
-    if quantized:
-        ck = gather(new_cache["k"]).astype(x.dtype) \
-            * gather(new_cache["k_scale"])[..., None].astype(x.dtype)
-        cv = gather(new_cache["v"]).astype(x.dtype) \
-            * gather(new_cache["v_scale"])[..., None].astype(x.dtype)
-    else:
-        ck = gather(new_cache["k"])
-        cv = gather(new_cache["v"])
-    g = n_heads // n_kv
-    qg = q.reshape(b, 1, n_kv, g, head_dim).astype(ck.dtype)
-    s = jnp.einsum("bqhgd,bhkd->bhgqk", qg, ck,
-                   preferred_element_type=jnp.float32)
-    s = s / jnp.sqrt(head_dim)
-    valid = jnp.arange(seq)[None, None, None, None, :] \
-        <= pos[:, None, None, None, None]
-    s = jnp.where(valid, s, -jnp.inf)
-    w = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhgqk,bhkd->bqhgd", w.astype(cv.dtype), cv,
-                   preferred_element_type=jnp.float32)
+        if quantized:
+            ck = gather(new_cache["k"]).astype(x.dtype) \
+                * gather(new_cache["k_scale"])[..., None].astype(x.dtype)
+            cv = gather(new_cache["v"]).astype(x.dtype) \
+                * gather(new_cache["v_scale"])[..., None].astype(x.dtype)
+        else:
+            ck = gather(new_cache["k"])
+            cv = gather(new_cache["v"])
+        g = n_heads // n_kv
+        qg = q.reshape(b, 1, n_kv, g, head_dim).astype(ck.dtype)
+        s = jnp.einsum("bqhgd,bhkd->bhgqk", qg, ck,
+                       preferred_element_type=jnp.float32)
+        s = s / jnp.sqrt(head_dim)
+        valid = jnp.arange(seq)[None, None, None, None, :] \
+            <= pos[:, None, None, None, None]
+        s = jnp.where(valid, s, -jnp.inf)
+        w = jax.nn.softmax(s, axis=-1)
+        o = jnp.einsum("bhgqk,bhkd->bqhgd", w.astype(cv.dtype), cv,
+                       preferred_element_type=jnp.float32)
     o = o.reshape(b, 1, n_heads * head_dim).astype(x.dtype)
     return linear(o, p["wo"]), new_cache
 
@@ -501,63 +506,66 @@ def attn_verify(
         k_rows = k.astype(cache["k"].dtype)
         v_rows = v.astype(cache["v"].dtype)
 
-    new_cache = dict(cache)
-    if block_tables is None:
-        seq = cache["k"].shape[2]
-        rows = jnp.arange(b)[:, None]  # (B, 1) broadcasts with posm
-        col = jnp.where(posm < seq, posm, seq)  # out-of-store -> dropped
-        new_cache["k"] = cache["k"].at[rows, :, col, :].set(k_rows, mode="drop")
-        new_cache["v"] = cache["v"].at[rows, :, col, :].set(v_rows, mode="drop")
-        if quantized:
-            new_cache["k_scale"] = cache["k_scale"].at[rows, :, col].set(
-                ks_rows, mode="drop")
-            new_cache["v_scale"] = cache["v_scale"].at[rows, :, col].set(
-                vs_rows, mode="drop")
-    else:
-        ps = page_size
-        w_pages = block_tables.shape[1]
-        seq = w_pages * ps
-        logical = jnp.clip(posm // ps, 0, w_pages - 1)
-        page = jnp.take_along_axis(block_tables, logical, axis=1)  # (B, T)
-        page = jnp.where(posm < seq, page, 0)  # past the store -> trash page
-        off = posm % ps
-        new_cache["k"] = cache["k"].at[page, :, off, :].set(k_rows)
-        new_cache["v"] = cache["v"].at[page, :, off, :].set(v_rows)
-        if quantized:
-            new_cache["k_scale"] = cache["k_scale"].at[page, :, off].set(ks_rows)
-            new_cache["v_scale"] = cache["v_scale"].at[page, :, off].set(vs_rows)
+    with jax.named_scope("kv_write"):
+        new_cache = dict(cache)
+        if block_tables is None:
+            seq = cache["k"].shape[2]
+            rows = jnp.arange(b)[:, None]  # (B, 1) broadcasts with posm
+            col = jnp.where(posm < seq, posm, seq)  # out-of-store -> dropped
+            new_cache["k"] = cache["k"].at[rows, :, col, :].set(k_rows, mode="drop")
+            new_cache["v"] = cache["v"].at[rows, :, col, :].set(v_rows, mode="drop")
+            if quantized:
+                new_cache["k_scale"] = cache["k_scale"].at[rows, :, col].set(
+                    ks_rows, mode="drop")
+                new_cache["v_scale"] = cache["v_scale"].at[rows, :, col].set(
+                    vs_rows, mode="drop")
+        else:
+            ps = page_size
+            w_pages = block_tables.shape[1]
+            seq = w_pages * ps
+            logical = jnp.clip(posm // ps, 0, w_pages - 1)
+            page = jnp.take_along_axis(block_tables, logical, axis=1)  # (B, T)
+            page = jnp.where(posm < seq, page, 0)  # past the store -> trash page
+            off = posm % ps
+            new_cache["k"] = cache["k"].at[page, :, off, :].set(k_rows)
+            new_cache["v"] = cache["v"].at[page, :, off, :].set(v_rows)
+            if quantized:
+                new_cache["k_scale"] = cache["k_scale"].at[page, :, off].set(ks_rows)
+                new_cache["v_scale"] = cache["v_scale"].at[page, :, off].set(vs_rows)
 
-    if block_tables is None:
-        def fetch(key):
-            return new_cache[key]
-    else:
-        def fetch(key):
-            g = new_cache[key][block_tables]  # (B, W, KV, ps, ...)
-            g = jnp.moveaxis(g, 1, 2)  # (B, KV, W, ps, ...)
-            return g.reshape((b, n_kv, seq) + g.shape[4:])
+    with jax.named_scope("attention"):
+        if block_tables is None:
+            def fetch(key):
+                return new_cache[key]
+        else:
+            def fetch(key):
+                g = new_cache[key][block_tables]  # (B, W, KV, ps, ...)
+                g = jnp.moveaxis(g, 1, 2)  # (B, KV, W, ps, ...)
+                return g.reshape((b, n_kv, seq) + g.shape[4:])
 
-    if quantized:
-        ck = fetch("k").astype(x.dtype) * fetch("k_scale")[..., None].astype(x.dtype)
-        cv = fetch("v").astype(x.dtype) * fetch("v_scale")[..., None].astype(x.dtype)
-    else:
-        ck, cv = fetch("k"), fetch("v")
-    g = n_heads // n_kv
-    qg = q.reshape(b, t, n_kv, g, head_dim).astype(ck.dtype)
-    s = jnp.einsum("bqhgd,bhkd->bhgqk", qg, ck,
-                   preferred_element_type=jnp.float32)
-    s = s / jnp.sqrt(head_dim)
-    if tree is None:
-        # query t's frontier is pos + t: the cached prefix plus the window's
-        # earlier tokens and itself — causal across cache and window at once.
-        valid = (jnp.arange(ck.shape[2])[None, None, None, None, :]
-                 <= posm[:, None, None, :, None])
-    else:
-        _, vis = tree_layout(*tree)
-        valid = _tree_valid(vis, pos, t, ck.shape[2])[:, None, None, :, :]
-    s = jnp.where(valid, s, -jnp.inf)
-    w = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhgqk,bhkd->bqhgd", w.astype(cv.dtype), cv,
-                   preferred_element_type=jnp.float32)
+        if quantized:
+            ck = fetch("k").astype(x.dtype) * fetch("k_scale")[..., None].astype(x.dtype)
+            cv = fetch("v").astype(x.dtype) * fetch("v_scale")[..., None].astype(x.dtype)
+        else:
+            ck, cv = fetch("k"), fetch("v")
+        g = n_heads // n_kv
+        qg = q.reshape(b, t, n_kv, g, head_dim).astype(ck.dtype)
+        s = jnp.einsum("bqhgd,bhkd->bhgqk", qg, ck,
+                       preferred_element_type=jnp.float32)
+        s = s / jnp.sqrt(head_dim)
+        if tree is None:
+            # query t's frontier is pos + t: the cached prefix plus the
+            # window's earlier tokens and itself — causal across cache and
+            # window at once.
+            valid = (jnp.arange(ck.shape[2])[None, None, None, None, :]
+                     <= posm[:, None, None, :, None])
+        else:
+            _, vis = tree_layout(*tree)
+            valid = _tree_valid(vis, pos, t, ck.shape[2])[:, None, None, :, :]
+        s = jnp.where(valid, s, -jnp.inf)
+        w = jax.nn.softmax(s, axis=-1)
+        o = jnp.einsum("bhgqk,bhkd->bqhgd", w.astype(cv.dtype), cv,
+                       preferred_element_type=jnp.float32)
     o = o.reshape(b, t, n_heads * head_dim).astype(x.dtype)
     return linear(o, p["wo"]), new_cache
 

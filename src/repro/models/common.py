@@ -109,28 +109,30 @@ def linear(x: jnp.ndarray, w, b: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     a column shard may sum in another order — which is why the TPU path
     sends prefill through pim_matmul too.
     """
-    if not (isinstance(w, dict) and "codes" in w):
-        y = x @ w
-        return y if b is None else y + b.astype(y.dtype)
-    tp = "tp" in w
-    if tp and b is not None:
-        n_loc = w["codes"].shape[-1]
-        b = jax.lax.dynamic_slice_in_dim(
-            b, jax.lax.axis_index(TP_AXIS) * n_loc, n_loc)
-    if w["codes"].ndim == 2 and _kernels_enabled():
-        y = _linear_kernel(x, w, b)  # bias fused in the kernel epilogue
-    else:
-        y = x @ _dq_local(w, x.dtype)
-        if b is not None:
-            y = y + b.astype(y.dtype)
-    # Pin the output at the activation dtype, as a tp leaf's all-gather
-    # does by itself.  Otherwise the compiler may hand consumers the float32
-    # values from before the rounding (excess precision) on one device but
-    # not across a gather, and sharded greedy tokens would drift.
-    y = jax.lax.optimization_barrier(y)
-    if tp:
-        y = jax.lax.all_gather(y, TP_AXIS, axis=y.ndim - 1, tiled=True)
-    return y
+    with jax.named_scope("linear"):
+        if not (isinstance(w, dict) and "codes" in w):
+            y = x @ w
+            return y if b is None else y + b.astype(y.dtype)
+        tp = "tp" in w
+        if tp and b is not None:
+            n_loc = w["codes"].shape[-1]
+            b = jax.lax.dynamic_slice_in_dim(
+                b, jax.lax.axis_index(TP_AXIS) * n_loc, n_loc)
+        if w["codes"].ndim == 2 and _kernels_enabled():
+            y = _linear_kernel(x, w, b)  # bias fused in the kernel epilogue
+        else:
+            y = x @ _dq_local(w, x.dtype)
+            if b is not None:
+                y = y + b.astype(y.dtype)
+        # Pin the output at the activation dtype, as a tp leaf's all-gather
+        # does by itself.  Otherwise the compiler may hand consumers the
+        # float32 values from before the rounding (excess precision) on one
+        # device but not across a gather, and sharded greedy tokens would
+        # drift.
+        y = jax.lax.optimization_barrier(y)
+        if tp:
+            y = jax.lax.all_gather(y, TP_AXIS, axis=y.ndim - 1, tiled=True)
+        return y
 
 
 def weight_kn(w) -> tuple[int, int]:
@@ -249,11 +251,12 @@ def embed_lookup(table: jnp.ndarray, ids: jnp.ndarray) -> jnp.ndarray:
 
 def unembed(x: jnp.ndarray, table_or_w) -> jnp.ndarray:
     """Logits. ``table_or_w``: (V, D) tied table or (D, V) head weight."""
-    if isinstance(table_or_w, dict) and "codes" in table_or_w:
-        return linear(x, table_or_w)
-    if table_or_w.shape[0] > table_or_w.shape[1]:  # (V, D) tied
-        return x @ table_or_w.T
-    return x @ table_or_w
+    with jax.named_scope("lm_head"):
+        if isinstance(table_or_w, dict) and "codes" in table_or_w:
+            return linear(x, table_or_w)
+        if table_or_w.shape[0] > table_or_w.shape[1]:  # (V, D) tied
+            return x @ table_or_w.T
+        return x @ table_or_w
 
 
 def cross_entropy(logits: jnp.ndarray, labels: jnp.ndarray) -> jnp.ndarray:

@@ -106,15 +106,25 @@ state, moe batched expert capacity makes a tail window diverge from a
 full prefill under capacity pressure, and MLA's absorbed decode differs
 at ~1e-3.  Ineligible families (and draft mode) simply never hit.
 
-**Per-request telemetry** (``RequestRecord.slot``/``.events``,
-``ServeReport.counters``): every request carries span events —
+**Telemetry** (``RequestRecord.slot``/``.events``, ``ServeReport.counters``
+and ``.spans``): every request carries span events —
 ``{"name", "ts", "dur"?, ...}`` in engine-clock seconds — for admit
 (with cached/prefilled token counts), per-round decode (with tokens
 emitted), preempt, shed, and finish, plus one per-round counter sample
-(free/retained pages, prefix-hit tokens, effective k, queue depth).
+(free/retained pages, prefix-hit tokens, effective k, queue depth).  The
+serve loop itself records one host span per phase of each round
+(``engine.heartbeat``; ``engine.schedule`` with one nested
+``engine.admit`` per admit; then ``engine.idle``, or ``engine.dispatch``,
+``engine.wait`` on the chunk's outputs and ``engine.emit``) through one
+helper (``_Span``) that writes each span both to ``ServeReport.spans`` and,
+over the same interval, to the profiler as a ``TraceAnnotation``, so that
+a profiler trace shows the host's phases on the device trace's clock.  The
+model names its layers in the compiled programs with ``jax.named_scope``
+(``attention``, ``kv_write``, ``linear``, ``lm_head``; ``sample`` and
+``nonfinite`` here), which changes HLO metadata only.
 ``tools/trace_export.py`` turns a report into chrome-tracing JSON (one
-Perfetto track per slot + counter tracks); under a ``VirtualClock`` the
-trace is fully deterministic.
+Perfetto track per slot, an engine track, counter tracks); under a
+``VirtualClock`` the trace is fully deterministic.
 """
 from __future__ import annotations
 
@@ -652,10 +662,42 @@ class Request:
     rid: Optional[int] = None      # sampled-draw key id (default: trace index)
 
 
+class _Span:
+    """One host span of the serve loop, recorded twice over one interval:
+    as ``{"name", "ts", "dur", **args}`` on a span list (engine clock, as
+    ``RequestRecord.events``) and as a ``jax.profiler.TraceAnnotation``
+    with the same args, which lands on the profiler's host plane when a
+    trace is being taken (about 2 us a span when none is).  A context
+    manager, or ``close()`` where the span ends inside a block; args known
+    only at the end go in ``late`` before it closes."""
+
+    def __init__(self, spans: list, now, name: str, **args):
+        self._spans, self._now = spans, now
+        self.name, self.args, self.late = name, args, {}
+        self.ts = now()
+        self._ann = jax.profiler.TraceAnnotation(name, **args)
+        self._ann.__enter__()
+
+    def close(self) -> None:
+        if self.late:
+            self._ann.set_metadata(**self.late)
+        self._ann.__exit__(None, None, None)
+        self._spans.append({"name": self.name, "ts": self.ts,
+                            "dur": self._now() - self.ts, **self.args,
+                            **self.late})
+
+    def __enter__(self) -> "_Span":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.close()
+
+
 def _nonfinite_rows(logits, live=True):
     """How many (live) rows of ``logits`` (..., V) hold a NaN or Inf."""
-    bad = ~jnp.all(jnp.isfinite(logits), axis=-1)
-    return jnp.sum(bad & live, dtype=jnp.int32)
+    with jax.named_scope("nonfinite"):
+        bad = ~jnp.all(jnp.isfinite(logits), axis=-1)
+        return jnp.sum(bad & live, dtype=jnp.int32)
 
 
 def _admit_body(params, cfg: ModelConfig, cache, prompt, length, slot, pages,
@@ -673,9 +715,10 @@ def _admit_body(params, cfg: ModelConfig, cache, prompt, length, slot, pages,
                             pages=pages, slot=slot)
     lg = jax.lax.dynamic_index_in_dim(logits, length - 1, axis=1,
                                       keepdims=False)  # (1, V)
-    tok0 = sample_rows(
-        lg, None if greedy else draw_keys(key, rid[None], 0, TAG_TOKEN),
-        greedy=greedy, temperature=temperature, top_k=top_k)[0]
+    with jax.named_scope("sample"):
+        tok0 = sample_rows(
+            lg, None if greedy else draw_keys(key, rid[None], 0, TAG_TOKEN),
+            greedy=greedy, temperature=temperature, top_k=top_k)[0]
     return cache, tok0, _nonfinite_rows(lg)
 
 
@@ -815,17 +858,18 @@ def _decode_chunk_body(params, cfg: ModelConfig, cache, tok, pos, n_out, done,
         tok, cache, pos, n_out, done, bad = carry
         lg, cache = decode_step(params, cfg, tok, cache, pos, extras,
                                 page_size=page_size)
-        nxt = sample_rows(
-            lg[:, -1, :],
-            None if greedy else draw_keys(key, rids, n_out, TAG_TOKEN),
-            greedy=greedy, temperature=temperature, top_k=top_k)
         live = ~done
         bad = bad + _nonfinite_rows(lg[:, -1, :], live)
-        emit = jnp.where(live, nxt, jnp.int32(pad_id))
-        pos = jnp.where(live, pos + 1, pos)
-        n_out = jnp.where(live, n_out + 1, n_out)
-        hit = jnp.any(emit[:, None] == stops, axis=1)
-        done = done | (live & hit) | (n_out >= max_new)
+        with jax.named_scope("sample"):
+            nxt = sample_rows(
+                lg[:, -1, :],
+                None if greedy else draw_keys(key, rids, n_out, TAG_TOKEN),
+                greedy=greedy, temperature=temperature, top_k=top_k)
+            emit = jnp.where(live, nxt, jnp.int32(pad_id))
+            pos = jnp.where(live, pos + 1, pos)
+            n_out = jnp.where(live, n_out + 1, n_out)
+            hit = jnp.any(emit[:, None] == stops, axis=1)
+            done = done | (live & hit) | (n_out >= max_new)
         return (emit[:, None], cache, pos, n_out, done, bad), (emit, live)
 
     carry, (emits, lives) = jax.lax.scan(
@@ -1459,6 +1503,10 @@ class ContinuousBatchingEngine:
             lowered = _decode_chunk_sharded.lower(*args, mesh=self.mesh, **kw)
         return lowered.compile().as_text()
 
+    def _span(self, name: str, **args) -> _Span:
+        """Open a host span of this serve call (``ServeReport.spans``)."""
+        return _Span(self._spans, self._now, name, **args)
+
     def _shed(self, records, report, ridx: int, reason: str) -> None:
         rec = records[ridx]
         rec.status, rec.reason = "shed", reason
@@ -1594,6 +1642,7 @@ class ContinuousBatchingEngine:
 
         self._records = records
         self._now = now
+        self._spans = report.spans
 
         ladder = DegradationLadder(
             policy.ladder if hardened else LadderConfig(enabled=False),
@@ -1641,9 +1690,11 @@ class ContinuousBatchingEngine:
         while self._queue or any(r >= 0 for r in self._slot_req):
             self.last_round = rnd
             if heartbeat is not None:
-                heartbeat()
+                with self._span("engine.heartbeat", round=rnd):
+                    heartbeat()
             if chaos is not None:
                 chaos.crash(rnd)  # raises EngineCrash; supervisor replays
+            schedule = self._span("engine.schedule", round=rnd)
             retries_before = report.retries
             preempt_before = self.preemptions
             sheds_round = 0
@@ -1710,8 +1761,11 @@ class ContinuousBatchingEngine:
                         blocked = True
                         break
                     self._queue.popleft()
-                    info = self._admit(requests, slot, ridx, greedy,
-                                       temperature, top_k, resume=rs)
+                    with self._span("engine.admit", round=rnd, slot=slot,
+                                    prompt_tokens=len(req.prompt)) as sp:
+                        info = self._admit(requests, slot, ridx, greedy,
+                                           temperature, top_k, resume=rs)
+                        sp.late["prefilled_tokens"] = info["prefilled_tokens"]
                     rec = records[ridx]
                     rec.slot = slot
                     if rec.t_admit is None:
@@ -1729,6 +1783,8 @@ class ContinuousBatchingEngine:
                     self._finish(requests, records, slot, t_adm)
             live = [s for s in range(self.slots) if self._slot_req[s] >= 0]
             if not live:
+                schedule.close()
+                idle = self._span("engine.idle", round=rnd)
                 if self._queue and not admitted_any:
                     head = self._queue[0]
                     if hardened and requests[head].arrival > now():
@@ -1744,6 +1800,7 @@ class ContinuousBatchingEngine:
                             "page pool too small to admit request with "
                             f"prompt {len(requests[head].prompt)} tokens; "
                             "increase num_pages")
+                idle.close()
                 rnd += 1
                 continue
             # ---- effective scheduling parameters for this round (ladder)
@@ -1862,6 +1919,7 @@ class ContinuousBatchingEngine:
                 ridx = self._slot_req[s0]
                 self._shed(records, report, ridx, "oom")
                 self._retire(s0)
+                schedule.close()
                 rnd += 1
                 continue
             if withheld:
@@ -1886,11 +1944,14 @@ class ContinuousBatchingEngine:
                 skew += lag
                 report.straggle_s += lag
 
+            schedule.close()
             n0 = self._n_out.copy()
             t_round_start = now()
-            self._cache["block_tables"] = jnp.asarray(self._bt)
-            self.decode_chunk_iters += eff_chunk
+            ema = None
             try:
+                dispatch = self._span("engine.dispatch", round=rnd)
+                self._cache["block_tables"] = jnp.asarray(self._bt)
+                self.decode_chunk_iters += eff_chunk
                 if spec_on and self.spec.tree_fan:
                     step = (spec_mod._spec_tree_chunk if self.mesh is None
                             else functools.partial(
@@ -1953,7 +2014,6 @@ class ContinuousBatchingEngine:
                             accept=self.spec.accept,
                             typical_eps=self.spec.typical_eps,
                             typical_delta=self.spec.typical_delta)
-                    self._acc_ema = np.array(ema)
                 else:
                     step = (_decode_chunk if self.mesh is None
                             else functools.partial(_decode_chunk_sharded,
@@ -1970,6 +2030,16 @@ class ContinuousBatchingEngine:
                         chunk=eff_chunk, page_size=self.page_size,
                         greedy=bool(greedy), top_k=int(top_k),
                         pad_id=self.pad_id)
+                dispatch.close()
+                with self._span("engine.wait", round=rnd):
+                    if spec_on:
+                        emits, ms = np.asarray(emits), np.asarray(ms)
+                    else:
+                        emits, lives = np.asarray(emits), np.asarray(lives)
+                emit = self._span("engine.emit", round=rnd)
+                if ema is not None:
+                    self._acc_ema = np.array(ema)
+                if not spec_on:
                     self.nonfinite_logits += int(bad)
             except (ChunkFault, EngineCrash):
                 raise
@@ -1983,7 +2053,6 @@ class ContinuousBatchingEngine:
             if spec_on:
                 self._hist = np.array(hist)
                 self._wctr = np.array(wctr)
-                emits, ms = np.asarray(emits), np.asarray(ms)
                 for t in range(eff_chunk):
                     for slot in range(self.slots):
                         mm = int(ms[t, slot])
@@ -2007,7 +2076,6 @@ class ContinuousBatchingEngine:
                                 f"(request {ridx}): hist emissions "
                                 f"{got.tolist()} != outputs {out}")
             else:
-                emits, lives = np.asarray(emits), np.asarray(lives)
                 cnt = n0.copy()
                 for t in range(eff_chunk):
                     for slot in range(self.slots):
@@ -2043,14 +2111,17 @@ class ContinuousBatchingEngine:
             if hardened:
                 skew += policy.round_time
             t_end = now()
+            emitted = 0
             for slot in live:
                 ridx_s = self._slot_req[slot]
                 if ridx_s < 0:
                     continue  # preempted during this round's top-up
+                n_tok = int(self._n_out[slot] - n0[slot])
+                emitted += n_tok
                 records[ridx_s].events.append(
                     {"name": "decode", "ts": t_round_start,
                      "dur": t_end - t_round_start, "round": rnd,
-                     "tokens": int(self._n_out[slot] - n0[slot])})
+                     "tokens": n_tok})
             for slot in range(self.slots):
                 if self._slot_req[slot] >= 0 and self._done[slot]:
                     # Per-slot completion at chunk granularity: interpolate
@@ -2093,6 +2164,8 @@ class ContinuousBatchingEngine:
                 ladder.update(rnd, bool(bad), "+".join(bad))
                 if snap_every and rnd % snap_every == 0:
                     self._take_snapshot(records, policy, rnd)
+            emit.late["tokens"] = emitted
+            emit.close()
             rnd += 1
 
         self._pool_poisoned = False  # round loop completed normally

@@ -132,9 +132,10 @@ class RequestRecord:
     ``prefilled_tokens``/``cow`` on admit, ``tokens``/``round`` on
     decode, ``reason`` on shed).  Event names: ``admit``, ``decode``
     (one per scheduling round the request was live in), ``preempt``,
-    ``shed``, ``finish``.  ``tools/trace_export.py`` renders these as
-    chrome-tracing/Perfetto tracks; under a VirtualClock the stream is
-    deterministic."""
+    ``shed``, ``finish``.  The engine's own phases of each round are host
+    spans on ``ServeReport.spans``, not per request.
+    ``tools/trace_export.py`` renders these as chrome-tracing/Perfetto
+    tracks; under a VirtualClock the stream is deterministic."""
 
     status: str = "pending"
     reason: str = ""
@@ -170,6 +171,15 @@ class ServeReport:
     # retained page counts, cumulative prefix-hit tokens, effective k,
     # queue depth — the counter tracks of tools/trace_export.py.
     counters: list = dataclasses.field(default_factory=list)
+    # Host spans of the serve loop, engine clock: ``{"name", "ts", "dur",
+    # "round", ...args}``, each also a profiler ``TraceAnnotation`` over
+    # the same interval.  Per round: ``engine.heartbeat``, then
+    # ``engine.schedule`` (holding one ``engine.admit`` per admit, with
+    # ``slot``/``prompt_tokens``/``prefilled_tokens``), then either
+    # ``engine.idle`` or ``engine.dispatch``, ``engine.wait`` and
+    # ``engine.emit`` (with ``tokens``).  Rendered as the ``engine`` track
+    # by tools/trace_export.py; read by the benchmark's scheduler metrics.
+    spans: list = dataclasses.field(default_factory=list)
     prefix_hits: int = 0        # admits served (partly) from the prefix trie
     prefix_hit_tokens: int = 0  # prompt tokens aliased instead of prefilled
     prefill_tokens: int = 0     # prompt tokens actually computed

@@ -116,6 +116,12 @@ def test_trace_export_deterministic_and_perfetto_valid(tmp_path):
     assert n > len(reqs)  # at least admit+finish per request plus metas
     names = {e["name"].split()[0] for e in t1["traceEvents"]}
     assert {"admit", "decode", "finish", "free_pages"} <= names
+    engine = [e for e in t1["traceEvents"] if e.get("cat") == "engine"]
+    assert {e["name"] for e in engine} >= {
+        "engine.schedule", "engine.admit", "engine.dispatch", "engine.wait",
+        "engine.emit"}
+    assert len({e["tid"] for e in engine}) == 1
+    assert all(e["ph"] == "X" and "round" in e["args"] for e in engine)
 
     router = ReplicaRouter(
         [_mk(cfg, params, clock=VirtualClock()) for _ in range(2)])
@@ -127,6 +133,9 @@ def test_trace_export_deterministic_and_perfetto_valid(tmp_path):
     reloaded = json.loads(path.read_text())
     assert trace_export.validate_trace(reloaded) == n
     assert {e["pid"] for e in reloaded["traceEvents"]} == {0, 1}
+    tracks = {(e["pid"], e["args"]["name"]) for e in reloaded["traceEvents"]
+              if e["name"] == "thread_name"}
+    assert {(0, "engine"), (1, "engine")} <= tracks
     assert reloaded["otherData"]["assignments"] == rrep.assignments
 
 

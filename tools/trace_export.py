@@ -1,14 +1,19 @@
 """Export serving telemetry as chrome-tracing (Perfetto-loadable) JSON.
 
 ``serve_detailed`` stamps span events on each ``RequestRecord`` (admit /
-decode / preempt / shed / finish; see resilience.RequestRecord) and one
-counter sample per dispatched round on ``ServeReport.counters``.  This
-module renders them in the Trace Event Format that chrome://tracing and
+decode / preempt / shed / finish; see resilience.RequestRecord), one
+counter sample per dispatched round on ``ServeReport.counters``, and the
+host spans of each round's phases on ``ServeReport.spans``.  This module
+renders them in the Trace Event Format that chrome://tracing and
 https://ui.perfetto.dev load directly:
 
 * one process (pid) per engine replica, one thread track (tid) per batch
   slot — ``ph:"X"`` complete events for decode rounds (they have extent),
   ``ph:"i"`` instants for admit/preempt/shed/finish;
+* one ``engine`` thread track per replica of ``ph:"X"`` complete events,
+  one per host span (``engine.schedule`` with its nested
+  ``engine.admit``, ``engine.dispatch``, ``engine.wait``, ``engine.emit``,
+  ``engine.idle``, ``engine.heartbeat``);
 * counter tracks (``ph:"C"``) for free/retained pages, pages in use,
   cumulative prefix-hit tokens, effective speculation k, queue depth and
   retries.
@@ -32,6 +37,7 @@ _US = 1e6   # engine-clock seconds -> trace microseconds
 _COUNTER_KEYS = ("free_pages", "retained_pages", "pages_in_use",
                  "prefix_hit_tokens", "eff_k", "queued", "retries")
 _INSTANT = ("admit", "preempt", "shed", "finish")
+_ENGINE_TID = 1 << 16  # the engine track, clear of the slot tracks
 
 
 def _meta(pid: int, name: str, tid: int = 0, kind: str = "process_name"):
@@ -69,6 +75,14 @@ def report_to_trace(report, pid: int = 0, process_name: str = "engine",
                            "s": "t", "cat": e["name"], "args": args})
     for tid in sorted(tids):
         ev.append(_meta(pid, f"slot {tid}", tid, "thread_name"))
+    if report.spans:
+        ev.append(_meta(pid, "engine", _ENGINE_TID, "thread_name"))
+    for s in report.spans:
+        ev.append({"name": s["name"], "ph": "X", "pid": pid,
+                   "tid": _ENGINE_TID, "ts": s["ts"] * _US,
+                   "dur": max(s["dur"], 0.0) * _US, "cat": "engine",
+                   "args": {k: v for k, v in s.items()
+                            if k not in ("name", "ts", "dur")}})
     for c in report.counters:
         ts = c.get("ts", 0.0) * _US
         for k in _COUNTER_KEYS:
