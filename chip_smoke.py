@@ -7,9 +7,11 @@ through ``repro.launch.serve.serve`` — the continuous-batching engine with
 Eight requests, prompts of 128 or 512 tokens, 32 to 64 new tokens each.
 Checks: every request finishes with its ``max_new`` tokens, no logits row
 holds a NaN or Inf, a second serve repeats the same tokens, the compiled
-decode chunk contains the kernel (``tpu_custom_call``), and ``pim_matvec``
+decode chunk contains the kernel (``tpu_custom_call``), ``pim_matvec``
 (decode) and ``pim_matmul`` (prefill, at both prompt lengths) agree with
-their ``kernels.ref`` oracle at the model's shapes, int8 and int4.
+their ``kernels.ref`` oracle at the model's shapes, int8 and int4, and
+``paged_decode_attention`` agrees with the whole-table gather it replaced
+at the chat cell's attention shape (8 slots, 640 pages of 8 a row).
 
 ``--chips 4``: only the tensor-sharded decode path and what it is compared
 with.  starcoder2-7b at int8 on device 0, then the same model (the same
@@ -140,6 +142,49 @@ def kernel_check(name: str, bits: int, rows: int, seed: int):
     return worst_err, worst_ratio, worst_rel
 
 
+# Largest error accepted over the largest |gather| value for the paged
+# attention kernel.  Both paths round the softmax probabilities to bfloat16
+# once (2**-9 relative), the kernel before it normalises and the gather
+# after, so they differ by about 2 * 2**-9 of the weighted sum at most
+# (5e-4 to 6e-4 read in interpret mode at 48 pages a row); a page read
+# twice, skipped or masked wrongly reads 1e-1 or more.
+ATTN_REL_LIMIT = 2.0 ** -8
+
+
+def paged_attention_check(seed: int):
+    """Max |kernel - gather| over max |gather|, ``kernels.paged_attention``
+    against ``models.attention.paged_gather_attention`` on the chip, at
+    qwen2-1.5b's heads (12 query, 2 KV, 128 wide) in bfloat16: shuffled
+    page ids, two free slots at length 1 on the trash page, the others at
+    lengths drawn up to the whole row."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.kernels.paged_attention import paged_decode_attention
+    from repro.models.attention import paged_gather_attention
+
+    slots, width, page = 8, 640, 8
+    n_heads, n_kv, d = 12, 2, 128
+    pages = slots * width + 1
+    rng = np.random.default_rng(seed)
+    bt = rng.permutation(np.arange(1, pages)).reshape(slots, width)
+    lengths = rng.integers(1, width * page + 1, slots)
+    lengths[-1] = width * page
+    bt[:2], lengths[:2] = 0, 1
+    key = jax.random.split(jax.random.PRNGKey(seed), 3)
+    k, v = (jax.random.normal(kk, (pages, n_kv, page, d), jnp.bfloat16)
+            for kk in key[:2])
+    q = jax.random.normal(key[2], (slots, n_heads, d), jnp.bfloat16)
+    bt, lengths = jnp.asarray(bt, jnp.int32), jnp.asarray(lengths, jnp.int32)
+    got = paged_decode_attention(q, k, v, bt, lengths)
+    want = jax.jit(lambda *a: paged_gather_attention(
+        *a, n_kv=n_kv, dtype=jnp.bfloat16))(
+        q[:, None], {"k": k, "v": v}, bt, lengths - 1)
+    want = want.reshape(slots, n_heads, d)
+    return float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
+
+
 # --------------------------------------------------------------- phases -----
 def phase_one_chip(seed: int) -> None:
     import jax
@@ -192,6 +237,12 @@ def phase_one_chip(seed: int) -> None:
                   f"{name} int{bits} {rows} rows: error exceeds its bound")
             check(rel <= REL_LIMIT, f"{name} int{bits} {rows} rows: error "
                   f"over max|ref| exceeds {REL_LIMIT:g}")
+    rel = paged_attention_check(seed)
+    print(f"paged_decode_attention vs the whole-table gather, 8 slots x 640 "
+          f"pages of 8, bf16: max err/max|gather| {rel:.3e} "
+          f"(pass: <= {ATTN_REL_LIMIT:g})")
+    check(rel <= ATTN_REL_LIMIT, "paged_decode_attention: error over "
+          f"max|gather| exceeds {ATTN_REL_LIMIT:g}")
 
 
 def _fingerprints(params) -> dict:

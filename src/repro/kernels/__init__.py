@@ -4,6 +4,8 @@
   pim_matvec   — decode-shaped (M<=8) variant with the fused epilogue
   bitplane     — bit-plane-decomposed matmul (PIM-semantic faithful form)
   fold_reduce  — OpMux-style log-step folding reduction
+  paged_attention — decode attention over each slot's live pages of the
+                 paged KV pool, read in place
   epilogue     — shared epilogue (scale/bias/activation/residual) + padding
   ops          — jit'd public wrappers;  ref — pure-jnp oracles
 """

@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .common import apply_rope, dense_init, linear, split_keys
+from .common import _kernels_enabled, apply_rope, dense_init, linear, split_keys
 
 CHUNKED_THRESHOLD = 8192
 KV_CHUNK = 512
@@ -369,6 +369,19 @@ def paged_kv_insert(pool: dict, dense: dict, pages: jnp.ndarray,
     return out
 
 
+def _paged_kernel_takes(head_dim: int) -> bool:
+    """Whether ``attn_decode_paged`` reads its pages through
+    ``kernels.paged_attention`` (the kernel dispatch of ``common.linear``).
+    On the chip Mosaic slices a page out of the pool only where its rows
+    fill whole 128-lane tiles; other head sizes keep the gather there."""
+    if not _kernels_enabled():
+        return False
+    from repro.kernels.ops import _interpret
+    from repro.kernels.paged_attention import LANES
+
+    return _interpret() or head_dim % LANES == 0
+
+
 def attn_decode_paged(
     p: dict,
     x: jnp.ndarray,  # (B, 1, D)
@@ -383,9 +396,13 @@ def attn_decode_paged(
     page_size: int,
 ) -> tuple[jnp.ndarray, dict]:
     """One decode step against the paged KV pool: scatter the new token's
-    K/V into its slot's current page, gather the slot's pages at the
-    contraction.  Per-slot ``pos`` makes every batch slot independent — the
-    carry the continuous-batching scheduler steps."""
+    K/V into its slot's current page, then attend to the slot's first
+    ``pos + 1`` positions.  An unquantized pool is read in place, only the
+    live pages, by ``kernels.paged_attention`` where the kernels are on
+    (``_paged_kernel_takes``); otherwise the slot's whole block-table row is
+    gathered at the contraction and the dead positions masked.  Per-slot
+    ``pos`` makes every batch slot independent — the carry the
+    continuous-batching scheduler steps."""
     b = x.shape[0]
     ps = page_size
     q = _split_heads(linear(x, p["wq"], p.get("bq")), n_heads, head_dim)
@@ -410,44 +427,70 @@ def attn_decode_paged(
             new_cache["k_scale"] = cache["k_scale"].at[page, :, off].set(k_sc[:, :, 0])
             new_cache["v_scale"] = cache["v_scale"].at[page, :, off].set(v_sc[:, :, 0])
         else:
-            new_cache["k"] = cache["k"].at[page, :, off, :].set(
+            # One head_dim row per slot and KV head.  For a (KV, D) window
+            # XLA lays the pool out with KV minor, and copies the whole pool
+            # back to row-major for paged_decode_attention, every layer.
+            row = (page[:, None], jnp.arange(n_kv)[None, :], off[:, None])
+            new_cache["k"] = cache["k"].at[row].set(
                 k_t[:, :, 0, :].astype(cache["k"].dtype))
-            new_cache["v"] = cache["v"].at[page, :, off, :].set(
+            new_cache["v"] = cache["v"].at[row].set(
                 v_t[:, :, 0, :].astype(cache["v"].dtype))
     with jax.named_scope("attention"):
-        # Gather the slot's pages: (B, W, KV, ps, D) -> (B, KV, W*ps, D) —
-        # the same head-major layout the dense contraction reads, just
-        # assembled from the block table.  The gather is a transient; only
-        # the pool persists.
-        w_pages = block_tables.shape[1]
-        seq = w_pages * ps
+        if not quantized and _paged_kernel_takes(head_dim):
+            # Read each slot's live pages in place.
+            from repro.kernels.ops import _interpret
+            from repro.kernels.paged_attention import paged_decode_attention
 
-        def gather(pool):
-            g = pool[block_tables]  # (B, W, KV, ps, ...)
-            g = jnp.moveaxis(g, 1, 2)  # (B, KV, W, ps, ...)
-            return g.reshape((b, n_kv, seq) + g.shape[4:])
-
-        if quantized:
-            ck = gather(new_cache["k"]).astype(x.dtype) \
-                * gather(new_cache["k_scale"])[..., None].astype(x.dtype)
-            cv = gather(new_cache["v"]).astype(x.dtype) \
-                * gather(new_cache["v_scale"])[..., None].astype(x.dtype)
+            o = paged_decode_attention(
+                q.reshape(b, n_heads, head_dim), new_cache["k"],
+                new_cache["v"], block_tables, pos + 1,
+                interpret=_interpret())
         else:
-            ck = gather(new_cache["k"])
-            cv = gather(new_cache["v"])
-        g = n_heads // n_kv
-        qg = q.reshape(b, 1, n_kv, g, head_dim).astype(ck.dtype)
-        s = jnp.einsum("bqhgd,bhkd->bhgqk", qg, ck,
-                       preferred_element_type=jnp.float32)
-        s = s / jnp.sqrt(head_dim)
-        valid = jnp.arange(seq)[None, None, None, None, :] \
-            <= pos[:, None, None, None, None]
-        s = jnp.where(valid, s, -jnp.inf)
-        w = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("bhgqk,bhkd->bqhgd", w.astype(cv.dtype), cv,
-                       preferred_element_type=jnp.float32)
+            o = paged_gather_attention(q, new_cache, block_tables, pos,
+                                       n_kv=n_kv, dtype=x.dtype)
     o = o.reshape(b, 1, n_heads * head_dim).astype(x.dtype)
     return linear(o, p["wo"]), new_cache
+
+
+def paged_gather_attention(q, pool: dict, block_tables, pos, *, n_kv: int,
+                           dtype) -> jnp.ndarray:
+    """Attention of ``q`` (B, 1, H, D) over each slot's first ``pos + 1``
+    positions, by gathering the slot's whole block-table row of ``pool``
+    (``k``/``v``; an int8 pool's codes times ``k_scale``/``v_scale``,
+    dequantized to ``dtype``) and masking the dead positions.  Returns
+    (B, 1, KV, H / KV, D) float32."""
+    b, _, n_heads, head_dim = q.shape
+    ps = pool["k"].shape[2]
+    # Gather the slot's pages: (B, W, KV, ps, D) -> (B, KV, W*ps, D) — the
+    # same head-major layout the dense contraction reads, just assembled
+    # from the block table.  The gather is a transient; only the pool
+    # persists.
+    seq = block_tables.shape[1] * ps
+
+    def gather(leaf):
+        g = leaf[block_tables]  # (B, W, KV, ps, ...)
+        g = jnp.moveaxis(g, 1, 2)  # (B, KV, W, ps, ...)
+        return g.reshape((b, n_kv, seq) + g.shape[4:])
+
+    if "k_scale" in pool:
+        ck = gather(pool["k"]).astype(dtype) \
+            * gather(pool["k_scale"])[..., None].astype(dtype)
+        cv = gather(pool["v"]).astype(dtype) \
+            * gather(pool["v_scale"])[..., None].astype(dtype)
+    else:
+        ck = gather(pool["k"])
+        cv = gather(pool["v"])
+    g = n_heads // n_kv
+    qg = q.reshape(b, 1, n_kv, g, head_dim).astype(ck.dtype)
+    s = jnp.einsum("bqhgd,bhkd->bhgqk", qg, ck,
+                   preferred_element_type=jnp.float32)
+    s = s / jnp.sqrt(head_dim)
+    valid = jnp.arange(seq)[None, None, None, None, :] \
+        <= pos[:, None, None, None, None]
+    s = jnp.where(valid, s, -jnp.inf)
+    w = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhgqk,bhkd->bqhgd", w.astype(cv.dtype), cv,
+                      preferred_element_type=jnp.float32)
 
 
 def attn_verify(

@@ -27,8 +27,10 @@ finishes, and the dense cache preallocates ``B * max_seq`` tokens.
   ``(P, page_size, rank)``), shared across all batch slots.  A slot's
   ``block_tables`` row (width ``max_seq / page_size``) maps its logical page
   ``i`` — positions ``[i*page_size, (i+1)*page_size)`` — to a pool page id.
-  Decode scatters the new token's K/V through the table and gathers the
-  slot's pages at the contraction (``models.attention.attn_decode_paged``).
+  Decode scatters the new token's K/V through the table and reads the
+  slot's pages at the contraction (``models.attention.attn_decode_paged``:
+  only the live ones, in place, through ``kernels.paged_attention`` where
+  the kernels are on; else a gather of the whole row).
   Page 0 is reserved as the trash page: inactive slots write there, so
   freed pages can be re-issued without cross-slot corruption.  Cache memory
   therefore scales with live tokens (pages in use), not ``B * max_seq``.
@@ -111,7 +113,9 @@ and ``.spans``): every request carries span events —
 ``{"name", "ts", "dur"?, ...}`` in engine-clock seconds — for admit
 (with cached/prefilled token counts), per-round decode (with tokens
 emitted), preempt, shed, and finish, plus one per-round counter sample
-(free/retained pages, prefix-hit tokens, effective k, queue depth).  The
+(free/retained pages, prefix-hit tokens, effective k, queue depth, and the
+pages the chunk's last step read, ``kv_pages_read``, against the whole
+block-table width of every slot, ``kv_pages_table``).  The
 serve loop itself records one host span per phase of each round
 (``engine.heartbeat``; ``engine.schedule`` with one nested
 ``engine.admit`` per admit; then ``engine.idle``, or ``engine.dispatch``,
@@ -2108,6 +2112,11 @@ class ContinuousBatchingEngine:
             self._pos = np.array(pos)
             self._n_out = np.array(n_out)
             self._done = np.array(done)
+            # Pages the chunk's last step read per slot, its first pos + 1
+            # positions (a plain step advances a slot it ran live), before
+            # retirements park finished slots at pos 0.
+            last = self._pos if spec_on else self._pos - lives[-1]
+            kv_pages_read = int(np.sum(last // self.page_size + 1))
             if hardened:
                 skew += policy.round_time
             t_end = now()
@@ -2146,7 +2155,9 @@ class ContinuousBatchingEngine:
                  "prefix_hit_tokens": self.prefix_hit_tokens,
                  "eff_k": int(eff_k) if spec_on else 0,
                  "queued": len(self._queue),
-                 "retries": report.retries})
+                 "retries": report.retries,
+                 "kv_pages_read": kv_pages_read,
+                 "kv_pages_table": self._bt.size})
             # ---- ladder signals + snapshot
             if hardened:
                 bad = []

@@ -131,3 +131,48 @@ def test_profiler_host_plane_holds_the_same_spans(model, speculate,
             assert stats["prefilled_tokens"] == s["prefilled_tokens"]
         if name == "engine.emit":
             assert stats["tokens"] == s["tokens"]
+
+
+@pytest.mark.parametrize("speculate", [None, 2], ids=["plain", "ngram"])
+def test_counters_hold_the_pages_each_chunk_read(model, speculate,
+                                                 monkeypatch):
+    """Every decode round's counters carry ``kv_pages_read``, the pages of
+    each slot's first ``pos + 1`` positions at the chunk's last step, and
+    ``kv_pages_table``, every slot's whole block-table row.  The positions
+    are the chunk program's own outputs: a plain step's live slots advanced
+    past the position they attended to; a speculative round counts from
+    the frontier it reached."""
+    from repro.serving import engine as eng
+    from repro.serving import speculative as spec_mod
+
+    seen = []
+    if speculate is None:
+        orig = eng._decode_chunk
+
+        def spy(*a, **kw):
+            out = orig(*a, **kw)
+            pos, lives = np.asarray(out[2]), np.asarray(out[6])
+            seen.append(pos - lives[-1])
+            return out
+
+        monkeypatch.setattr(eng, "_decode_chunk", spy)
+    else:
+        orig = spec_mod._spec_chunk
+
+        def spy(*a, **kw):
+            out = orig(*a, **kw)
+            seen.append(np.asarray(out[3]))
+            return out
+
+        monkeypatch.setattr(spec_mod, "_spec_chunk", spy)
+    rep = _serve(model, speculate)
+    decode_rounds = {e["round"] for r in rep.records for e in r.events
+                     if e["name"] == "decode"}
+    assert sorted(c["round"] for c in rep.counters) == sorted(decode_rounds)
+    assert len(seen) == len(rep.counters)
+    page_size, slots, width = 4, 2, 24 // 4
+    for c, last in zip(rep.counters, seen):
+        assert c["kv_pages_read"] == sum(-(-(int(p) + 1) // page_size)
+                                         for p in last)
+        assert c["kv_pages_table"] == slots * width
+        assert 0 < c["kv_pages_read"] <= c["kv_pages_table"]

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels.paged_attention import paged_decode_attention
 from repro.kernels.pim_matmul import pim_matmul
 from repro.kernels.pim_matvec import MAX_M, pim_matvec
 
@@ -95,4 +96,22 @@ def test_pim_matmul_compiles_for_v5e(one_chip, m, k, n, bits):
         _sds((m, k), jnp.bfloat16, one_chip),
         _sds((kw, n), jnp.int8, one_chip),
         _sds((1, n), jnp.float32, one_chip), bits=bits).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("slots,n_heads,n_kv,page,width", [
+    (8, 12, 2, 8, 640),  # qwen2-1.5b chat: 8 slots, max_seq 5120, page 8
+    (8, 48, 4, 8, 1024),  # starcoder2-15b's heads at an 8192-token row
+])
+def test_paged_decode_attention_compiles_for_v5e(one_chip, slots, n_heads,
+                                                 n_kv, page, width):
+    """The decode step's attention reads the pool's bfloat16 pages in place
+    at the served shapes: the block table and lengths in SMEM, a page of
+    every KV head per DMA."""
+    pages = slots * width + 1
+    pool = _sds((pages, n_kv, page, 128), jnp.bfloat16, one_chip)
+    text = paged_decode_attention.lower(
+        _sds((slots, n_heads, 128), jnp.bfloat16, one_chip), pool, pool,
+        _sds((slots, width), jnp.int32, one_chip),
+        _sds((slots,), jnp.int32, one_chip)).compile().as_text()
     assert "tpu_custom_call" in text
