@@ -1,5 +1,5 @@
-"""Plain reference of the decoder that ``qwen2-1.5b.json`` and
-``starcoder2-15b.json`` describe, as the repo's model computes it.
+"""Plain reference of the decoder that ``qwen2-1.5b.json`` describes, as
+the repo's model computes it.
 
 Pre-norm decoder: RMSNorm, grouped-query attention with RoPE on interleaved
 pairs and an optional bias on q, k and v, a gated SiLU MLP, a final RMSNorm

@@ -96,7 +96,8 @@ def set_up(cell: Cell, seed: int, seconds: float, devices):
     hp = ref.hyper(cell.config)
     cfg = serve.model_config(cell.config, hp)
     mesh = serve.mesh_for(cell.config)
-    params = weights.make(cfg, seed, mesh=mesh)
+    params = weights.make(cfg, seed, mesh=mesh,
+                          roles=getattr(ref, "LEAF_ROLES", None))
     marks.append(("weights", time.monotonic()))
     clock = serve.Clock()
     eng = serve.engine(cfg, params, cell.config, cell.mix, mesh, clock)

@@ -46,10 +46,12 @@ def main(argv=None) -> int:
     devices, _ = harness.require_chip(cell.chips)
     devices = devices[:cell.chips]
     harness.enable_cache()
-    hp = check.reference(cell.config["reference"]).hyper(cell.config)
+    ref = check.reference(cell.config["reference"])
+    hp = ref.hyper(cell.config)
     cfg = serve.model_config(cell.config, hp)
     mesh = serve.mesh_for(cell.config)
-    params = weights.make(cfg, args.seed, mesh=mesh)
+    params = weights.make(cfg, args.seed, mesh=mesh,
+                          roles=getattr(ref, "LEAF_ROLES", None))
     clock = serve.Clock()
     eng = serve.engine(cfg, params, cell.config, cell.mix, mesh, clock)
     chunk = int(cell.config["serving"]["chunk"])

@@ -59,6 +59,24 @@ def test_sound_run_is_correct_and_prints_its_checks():
     assert set(result["metrics"]) == {m["name"] for m in tiny_cell().end_to_end}
 
 
+def test_configuration_that_declares_leaf_roles_runs_to_correct(monkeypatch):
+    # tiny_contract.json's reference module declares a leaf role of its own.
+    monkeypatch.setattr(check, "CONFIGS", DATA)
+    seen = {}
+    set_up = harness.set_up
+
+    def spy(*a, **kw):
+        seen["st"] = set_up(*a, **kw)
+        return seen["st"]
+
+    monkeypatch.setattr(harness, "set_up", spy)
+    result, _ = run(tiny_cell(config="tiny_contract.json"))
+    assert result["correct"] is True
+    assert result["attempted"] >= 1 and result["failed"] == 0
+    # ln2 drawn as a shift about 0, not as a gain about 1
+    assert abs(float(jnp.mean(seen["st"].params["layers"]["ln2"]))) < 0.1
+
+
 @pytest.mark.parametrize("seed", [5, 11, 2**31 + 29])
 def test_no_request_is_lost_arriving_at_an_idle_engine(seed):
     # The tiny mix leaves the engine idle between most arrivals: each must be

@@ -9,6 +9,8 @@ float32 scale per output column, bfloat16 activations and biases).
 """
 from __future__ import annotations
 
+from collections.abc import Sequence
+
 ACT_BYTES = 2  # bfloat16 activations
 
 
@@ -21,8 +23,12 @@ def quant_linear(m: int, k: int, n: int, *, bias: bool = False,
     return flops, nbytes
 
 
-def layer_linears(hp: dict) -> list[tuple[str, int, int, bool]]:
-    """(name, K, N, bias) of one decoder layer's weight matrices."""
+def layer_linears(hp: dict) -> Sequence[tuple[str, int, int, bool]]:
+    """(name, K, N, bias) of one decoder layer's weight matrices: the list
+    that the reference's ``hyper()`` gives as ``hp["linears"]``, else a dense
+    decoder's with a gated MLP."""
+    if "linears" in hp:
+        return hp["linears"]
     d, f = hp["d_model"], hp["d_ff"]
     q, kv = hp["heads"] * hp["head_dim"], hp["kv_heads"] * hp["head_dim"]
     b = hp["qkv_bias"]
